@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import subprocess
 import sys
 import time
@@ -36,6 +37,9 @@ def main() -> None:
                     help="output path for the machine-readable record "
                          "(default: BENCH_<rev>.json)")
     args = ap.parse_args()
+
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
 
     from . import (accuracy, batched, fig5_2, fig5_3, fig5_5, fig5_8,
                    fmm_phases, guarded, kernel_tiles, serving, table5_1,

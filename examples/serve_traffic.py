@@ -18,6 +18,10 @@ import time
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import use_compile_cache
+
+use_compile_cache(".")
+
 import numpy as np
 
 from repro.data.synthetic import ragged_requests

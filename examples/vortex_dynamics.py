@@ -19,6 +19,10 @@ import time
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import use_compile_cache
+
+use_compile_cache(".")
+
 import numpy as np
 import jax.numpy as jnp
 

@@ -30,6 +30,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+#: Precision of every matrix product here: XLA's default f32 matmul on a
+#: TPU rounds operands to bf16, which costs the f32 FMM two orders of
+#: magnitude of accuracy at p = 17 (the binomial tables reach ~1e9).
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 # --------------------------------------------------------------------------
 # constant binomial matrices (numpy, float64; cast at use site)
@@ -109,7 +114,7 @@ def m2m_apply(a: jax.Array, t: jax.Array, mat: jax.Array) -> jax.Array:
     p = a.shape[-1] - 1
     ti = inv_pows(t, p)
     a_hat = a * ti
-    b_hat = jnp.einsum("...j,lj->...l", a_hat, mat)
+    b_hat = jnp.einsum("...j,lj->...l", a_hat, mat, precision=HIGHEST)
     return b_hat * pows(t, p)
 
 
@@ -117,7 +122,7 @@ def m2l_apply(a: jax.Array, r: jax.Array, mat: jax.Array) -> jax.Array:
     """Multipole around z_source -> local around z_target; r = z_t - z_s."""
     p = a.shape[-1] - 1
     a_hat = a * inv_pows(r, p)
-    b_hat = jnp.einsum("...k,lk->...l", a_hat, mat)
+    b_hat = jnp.einsum("...k,lk->...l", a_hat, mat, precision=HIGHEST)
     b = b_hat * inv_pows(-r, p)
     # log-source correction on the constant term
     return b.at[..., 0].add(a[..., 0] * jnp.log(r))
@@ -127,7 +132,7 @@ def l2l_apply(b: jax.Array, s: jax.Array, mat: jax.Array) -> jax.Array:
     """Shift local coefficients by s = z_child - z_parent."""
     p = b.shape[-1] - 1
     b_hat = b * pows(s, p)
-    c_hat = jnp.einsum("...j,lj->...l", b_hat, mat)
+    c_hat = jnp.einsum("...j,lj->...l", b_hat, mat, precision=HIGHEST)
     return c_hat * inv_pows(s, p)
 
 
@@ -317,38 +322,45 @@ def p2m_norm(w: jax.Array, q: jax.Array, inv_rho, p: int, kernel: str,
     return jnp.stack(coeffs, axis=-1)
 
 
+def _shift_operator(mat: np.ndarray, x: jax.Array) -> jax.Array:
+    """Per-box (..., p+1, p+1) shift operator ``mat[l, j] * x**|l - j|``.
+
+    ``mat`` is a triangular binomial table (lower: M2M, upper: L2L), so
+    only non-negative powers of the bounded ratio ``x`` occur and the
+    operator stays finite for coincident centers (x -> 0) — the property
+    the normalized scaled-Horner passes have — while the whole shift is
+    one gather of the power table and one multiply-reduce: a few HLO ops
+    per level instead of O(p**2) unrolled passes (which take the TPU
+    compiler minutes to schedule at p = 17)."""
+    p = mat.shape[0] - 1
+    l, j = np.indices(mat.shape)
+    power = pows(x, p)[..., np.abs(l - j)]
+    return power * mat.astype(power.real.dtype)
+
+
+def _apply_operator(op: jax.Array, c: jax.Array) -> jax.Array:
+    """out[..., l] = sum_j op[..., l, j] c[..., j] — elementwise on the
+    vector unit, exact in the working dtype (no MXU matmul precision)."""
+    return (op * c[..., None, :]).sum(axis=-1)
+
+
 def m2m_norm(a: jax.Array, u: jax.Array, ratio: jax.Array) -> jax.Array:
-    """Normalized M2M: u = t/rho_parent, ratio = rho_child/rho_parent."""
+    """Normalized M2M: u = t/rho_parent, ratio = rho_child/rho_parent.
+
+    out_l = sum_j A[l, j] u**(l-j) a_j ratio**j with A = ``m2m_matrix``
+    (Pascal columns plus the a_0 log-source column)."""
     p = a.shape[-1] - 1
-    c = [a[..., 0]]
-    w = jnp.ones_like(ratio)
-    for j in range(1, p + 1):
-        w = w * ratio
-        c.append(a[..., j] * w)
-    for k in range(p, 1, -1):            # Pascal pass with multiplier u
-        for j in range(k, p + 1):
-            c[j] = c[j] + u * c[j - 1]
-    w = jnp.ones_like(u)
-    out = [c[0]]
-    for j in range(1, p + 1):            # log-source correction
-        w = w * u
-        out.append(c[j] - c[0] * w / j)
-    return jnp.stack(out, axis=-1)
+    c = a * pows(ratio, p).astype(a.dtype)
+    return _apply_operator(_shift_operator(m2m_matrix(p), u), c)
 
 
 def l2l_norm(b: jax.Array, v: jax.Array, ratio: jax.Array) -> jax.Array:
-    """Normalized L2L: v = s/rho_parent, ratio = rho_child/rho_parent."""
+    """Normalized L2L: v = s/rho_parent, ratio = rho_child/rho_parent.
+
+    out_l = ratio**l sum_j C(j, l) v**(j-l) b_j (``l2l_matrix``)."""
     p = b.shape[-1] - 1
-    c = [b[..., j] for j in range(p + 1)]
-    for k in range(p + 1):               # suffix passes with multiplier v
-        for j in range(p - k, p):
-            c[j] = c[j] + v * c[j + 1]
-    w = jnp.ones_like(ratio)
-    out = [c[0]]
-    for l in range(1, p + 1):
-        w = w * ratio
-        out.append(c[l] * w)
-    return jnp.stack(out, axis=-1)
+    c = _apply_operator(_shift_operator(l2l_matrix(p), v), b)
+    return c * pows(ratio, p).astype(b.dtype)
 
 
 def m2l_norm(a: jax.Array, r: jax.Array, rho_s: jax.Array,
@@ -361,7 +373,7 @@ def m2l_norm(a: jax.Array, r: jax.Array, rho_s: jax.Array,
     pre = pows(rho_s / r, p)
     pre = pre.at[..., 0].set(1.0)        # a~_0 = a_0 (log strength)
     a_hat = a * pre
-    b_hat = jnp.einsum("...k,lk->...l", a_hat, mat)
+    b_hat = jnp.einsum("...k,lk->...l", a_hat, mat, precision=HIGHEST)
     b = b_hat * pows(-rho_t / r, p)
     return b.at[..., 0].add(a[..., 0] * jnp.log(r))
 

@@ -9,8 +9,9 @@ implementation is organized around.
 
 Single-sort scheme (DESIGN.md §8): the seed implementation re-sorted the
 full particle array once per split — ``2*nlevels`` O(N log N) lexsorts.
-This build sorts exactly **twice** (one ``argsort`` per coordinate) and
-then maintains, through every split, two id arrays ``A_x``/``A_y`` that
+This build sorts exactly **once** — one stable sort of the stacked (2, N)
+coordinate rows, an argsort per coordinate in a single op — and then
+maintains, through every split, two id arrays ``A_x``/``A_y`` that
 are segment-contiguous at the static rank bounds and internally sorted by
 x resp. y. Each median split is then O(N) sort-free work:
 
@@ -62,26 +63,31 @@ def _partition(order, left_of, starts_pos, mids_pos, offs_pos):
 
     ``order``: (N,) int32 particle ids, segment-contiguous at the static
     bounds and internally sorted by one coordinate. ``left_of``: (N,)
-    bool per particle *id*. ``starts_pos``/``mids_pos``/``offs_pos``:
-    (N,) static per-position segment start / median rank / offset within
-    the segment. Left entries keep their relative order in
-    ``[start, mid)``, right entries in ``[mid, end)`` — so both coordinate
-    orders survive every split without re-sorting.
+    int32 0/1 flag per particle *id* (not bool: the TPU compiler builds
+    an int32 scatter of 2**20 entries in under a second, a bool one in
+    tens of seconds). ``starts_pos``/``mids_pos``/``offs_pos``: (N,)
+    per-position segment start / median rank / offset within the
+    segment. Left entries keep their relative order in ``[start, mid)``,
+    right entries in ``[mid, end)`` — so both coordinate orders survive
+    every split without re-sorting.
     """
     f = left_of[order]
-    lefts = jnp.cumsum(f.astype(jnp.int32)) - f    # exclusive: lefts in [0, p)
+    # exclusive count of lefts before p; int32 even under x64
+    lefts = jnp.cumsum(f, dtype=jnp.int32) - f
     seg_l = lefts - lefts[starts_pos]              # lefts before p in segment
     seg_r = offs_pos - seg_l                       # rights before p in segment
-    dest = jnp.where(f, starts_pos + seg_l, mids_pos + seg_r)
+    dest = jnp.where(f > 0, starts_pos + seg_l, mids_pos + seg_r)
     return jnp.zeros_like(order).at[dest].set(order)
 
 
 def build_tree(z: jax.Array, q: jax.Array, cfg: FmmConfig) -> Tree:
     """Sort particles into the static pyramid layout and compute geometry.
 
-    Exactly two full-array sorts (one argsort per coordinate) regardless
-    of depth; everything else is cumsum/gather/scatter. The jaxpr
-    sort-count test in tests/test_topology.py pins this property.
+    One sort op regardless of depth — both coordinates' argsorts as the
+    rows of one (2, N) stable sort, which the TPU compiler also builds in
+    about a third of the time two 1-D sorts of 2**20 take; everything
+    else is cumsum/gather/scatter. The jaxpr sort-count test in
+    tests/test_topology.py pins this property.
     """
     rdt = cfg.real_dtype
     cdt = cfg.complex_dtype
@@ -94,37 +100,45 @@ def build_tree(z: jax.Array, q: jax.Array, cfg: FmmConfig) -> Tree:
     if L == 0:
         perm = jnp.arange(N, dtype=jnp.int32)
     else:
-        ax = jnp.argsort(x).astype(jnp.int32)      # full sort 1 (stable)
-        ay = jnp.argsort(y).astype(jnp.int32)      # full sort 2 (stable)
+        keys = jnp.stack([x, y])                   # (2, N)
+        _, order = jax.lax.sort(
+            (keys, jax.lax.broadcasted_iota(jnp.int32, keys.shape, 1)),
+            dimension=1, num_keys=1, is_stable=True)
+        ax, ay = order[0], order[1]                # argsort of x, of y
         sb = split_bounds(N, 2 * L)
         split_x = None
+        # Per-position segment bookkeeping is derived in-graph from the
+        # (2**s,)-entry bound tables: (N,)-sized numpy constants per
+        # split would embed hundreds of MB into the program at N ~ 1e6.
+        pos = jnp.arange(N, dtype=jnp.int32)
+        sid_pos = jnp.zeros(N, jnp.int32)          # segment of each rank
         for s in range(2 * L):
             b = sb[s]
             mids = sb[s + 1][1::2]
-            sid_pos = segment_ids(b)                       # static (N,)
-            starts_pos = jnp.asarray(b[:-1][sid_pos])
-            mids_pos = jnp.asarray(mids[sid_pos])
-            offs_pos = jnp.asarray(np.arange(N) - b[:-1][sid_pos])
+            starts_pos = jnp.asarray(b[:-1], jnp.int32)[sid_pos]
+            mids_pos = jnp.asarray(mids, jnp.int32)[sid_pos]
+            offs_pos = pos - starts_pos
             # sorted-run endpoints ARE the segment extents: 2 gathers/axis
-            jst, jla = jnp.asarray(b[:-1]), jnp.asarray(b[1:] - 1)
+            jst = jnp.asarray(b[:-1], jnp.int32)
+            jla = jnp.asarray(b[1:] - 1, jnp.int32)
             xmn, xmx = x[ax[jst]], x[ax[jla]]
             ymn, ymx = y[ay[jst]], y[ay[jla]]
             split_x = (xmx - xmn) >= (ymx - ymn)           # (2**s,)
             # positional "first half of my segment" flag, static per rank
-            pos_left = jnp.asarray(np.arange(N) < mids[sid_pos])
-            xleft = jnp.zeros(N, bool).at[ax].set(pos_left)
-            yleft = jnp.zeros(N, bool).at[ay].set(pos_left)
-            sid_of_id = jnp.zeros(N, jnp.int32).at[ax].set(
-                jnp.asarray(sid_pos))
+            pos_left = (pos < mids_pos).astype(jnp.int32)
+            xleft = jnp.zeros(N, jnp.int32).at[ax].set(pos_left)
+            yleft = jnp.zeros(N, jnp.int32).at[ay].set(pos_left)
+            sid_of_id = jnp.zeros(N, jnp.int32).at[ax].set(sid_pos)
             goes_left = jnp.where(split_x[sid_of_id], xleft, yleft)
             ax = _partition(ax, goes_left, starts_pos, mids_pos, offs_pos)
             ay = _partition(ay, goes_left, starts_pos, mids_pos, offs_pos)
+            # children of segment k are 2k (left half) and 2k+1
+            sid_pos = 2 * sid_pos + 1 - pos_left
         # Final rank order within each leaf = ascending in the axis its
         # parent split on (what the lexsort cascade leaves behind): both
         # id arrays are leaf-contiguous at the same static bounds, so the
         # choice is a positionwise select.
-        leaf_pos = segment_ids(sb[2 * L])                  # static (N,)
-        choose_x = split_x[jnp.asarray(leaf_pos // 2)]
+        choose_x = split_x[sid_pos // 2]
         perm = jnp.where(choose_x, ax, ay)
 
     xs, ys = x[perm], y[perm]

@@ -48,7 +48,11 @@ def lm_batch(dc: DataConfig, step: int):
 # ---------------------------------------------------------------------------
 
 def particles(dist: str, n: int, seed: int = 0):
-    """Complex positions in the unit square + unit-strength charges."""
+    """Complex positions in the unit square + normal random charges.
+
+    Host numpy arrays (complex128): the caller casts to its config's
+    ``complex_dtype`` before anything goes to the device — a TPU holds
+    no complex128 arrays."""
     rng = np.random.default_rng(seed)
 
     def rejected(gen):
@@ -70,7 +74,7 @@ def particles(dist: str, n: int, seed: int = 0):
     else:
         raise ValueError(dist)
     q = rng.normal(size=n)
-    return jnp.asarray(z), jnp.asarray(q + 0j)
+    return z, q + 0j
 
 
 def ragged_requests(num: int, *, seed: int = 0, median_n: int = 256,

@@ -38,7 +38,8 @@ class ShapeError(ValidationError):
 
 
 class DTypeError(ValidationError, TypeError):
-    """Argument dtype confusion (real positions, precision loss, ...)."""
+    """Argument dtype confusion (real positions, precision loss, ...), or
+    a config dtype the device cannot run (f64 on a TPU's kernels)."""
 
 
 class CapOverflowError(FmmError, RuntimeError):

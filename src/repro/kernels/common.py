@@ -18,6 +18,11 @@ single-problem callers run the same kernels at B = 1, and
 ``jax.vmap`` of the per-problem wrappers lowers onto the batched grid
 through their custom batching rules (see the ``*_op`` factories in each
 kernel module).
+
+"One launch" means one ``pallas_call`` in the program: it sits inside
+``run_chunked`` and runs once per chunk of target tiles, so that each
+run's scalar-prefetched slice of the lists fits SMEM — a single run for
+small problems, several at N = 2**20.
 """
 from __future__ import annotations
 
@@ -25,7 +30,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+
+#: Block index 0 for BlockSpec index maps. A bare Python ``0`` traces
+#: as int64 under ``jax_enable_x64``, and Mosaic refuses 64-bit index
+#: maps; every map returns this int32 zero instead.
+ZERO = np.int32(0)
 
 
 def default_interpret() -> bool:
@@ -97,59 +107,78 @@ def make_batched_op(batched_call):
     return op
 
 
+def row_view(a: jax.Array) -> jax.Array:
+    """(B, rows, width) source plane -> (B, rows, 1, width).
+
+    Mosaic takes a block only if its last two dims are (8, 128)-aligned
+    or equal the array's own; a one-row (1, width) block of a
+    (rows, width) plane is neither. With a singleton sublane axis the
+    staged block's last two dims equal the array's, so one source row
+    per list entry stays a legal DMA (see ``prefetch_row_specs``)."""
+    return a[..., None, :]
+
+
 def prefetch_row_specs(TB: int, SW: int, width: int):
-    """One ``(None, 1, width)`` scalar-prefetch-indexed BlockSpec per
-    staged source row on the batch-major grid: spec (w, tb) DMAs the row
-    of problem ``b`` named by list entry ``[b, i*TB + tb, s*SW + w]`` at
-    grid step (b, i, s). The list itself is the first scalar-prefetch
-    operand (``lref``, shape (B, ntile*TB, S_pad)); the leading ``None``
-    block dim squeezes the batch axis so the kernel body sees the same
-    (1, width) rows as a single-problem launch."""
+    """One ``(None, None, 1, width)`` scalar-prefetch-indexed BlockSpec
+    per staged source row on the batch-major grid, over a ``row_view``
+    plane: spec (w, tb) DMAs the row of problem ``b`` named by list
+    entry ``[b, i*TB + tb, s*SW + w]`` at grid step (b, i, s). The list
+    itself is the first scalar-prefetch operand (``lref``, shape
+    (B, ntile*TB, S_pad)); the squeezed batch and row dims leave the
+    kernel body the same (1, width) rows as a single-problem launch."""
 
     def make_src_map(w, tb):
         def src_map(b, i, s, lref):
-            return (b, lref[b, i * TB + tb, s * SW + w], 0)
+            return (b, lref[b, i * TB + tb, s * SW + w], ZERO, ZERO)
         return src_map
 
-    return [pl.BlockSpec((None, 1, width), make_src_map(w, tb))
+    return [pl.BlockSpec((None, None, 1, width), make_src_map(w, tb))
             for w in range(SW) for tb in range(TB)]
 
 
-def staged_list_specs(lists: jax.Array, dummy: int, TB: int, SW: int,
-                      width: int):
-    """Tiled scalar-prefetch staging shared by the P2P and M2L kernels.
+def slot_view(a: jax.Array, SW: int) -> jax.Array:
+    """(B, rows, steps*SW) per-slot plane -> (B, steps, rows, SW).
 
-    Pads the (B, nbox, S) interaction lists for a ``(B, ntile,
-    S_pad // SW)`` batch-major grid of ``TB``-target-box tiles — masked
-    (-1) and padding entries redirected to the all-zero ``dummy`` row —
-    and builds one ``(None, 1, width)`` scalar-prefetch-indexed
-    BlockSpec per staged source row (see ``prefetch_row_specs``).
+    Step-major, so a grid step's (TB, SW) slot block spans the array's
+    full last dim and stays legal for Mosaic at any ``stage_width``
+    (see ``slot_spec``)."""
+    B, rows, cols = a.shape
+    return a.reshape(B, rows, cols // SW, SW).transpose(0, 2, 1, 3)
 
-    Returns ``(padded_lists, src_specs, ntile)``.
+
+def slot_spec(TB: int, SW: int):
+    """BlockSpec of the (TB, SW) slot block of grid step (b, i, s) over
+    a ``slot_view`` plane."""
+    def slot_map(b, i, s, lref):
+        return (b, s, i, ZERO)
+    return pl.BlockSpec((None, None, TB, SW), slot_map)
+
+
+#: Bytes of interaction list one launch may hold in SMEM. Scalar
+#: prefetch places the whole list operand there (1 MiB per TPU v5e
+#: core, laid out in (8, 128) int32 tiles); a paper-scale problem's
+#: lists run to several MiB, so the staged kernels launch once per chunk
+#: of target tiles whose slice of the list fits this budget.
+SMEM_LIST_BYTES = 256 * 1024
+
+
+def staged_lists(lists_seq, dummy: int, TB: int, SW: int):
+    """Stage one or more interaction lists for a batch-major grid.
+
+    Each (B, nbox, S_k) region is dummy-redirected (masked -1 entries
+    point at the all-zero ``dummy`` row) and padded to a multiple of
+    ``SW`` so it owns a whole number of grid steps; the regions are
+    concatenated along the slot axis (one fused grid) and the box axis
+    is padded to ``nchunk`` equal chunks of whole ``TB``-box tiles, each
+    chunk's list small enough for SMEM (``SMEM_LIST_BYTES``).
+
+    Returns ``(combined, nchunk, region_steps)``: ``combined`` is
+    (B, rows, cols) with rows a multiple of ``nchunk * TB`` — callers pad
+    their target operands to the same rows — and ``region_steps[k]`` is
+    the number of SW-wide grid steps of region k (a fused kernel
+    branches on ``pl.program_id(2)`` against the running offsets).
     """
-    _, nbox, S = lists.shape
-    ntile = -(-nbox // TB)
-    S_pad = round_up(S, SW)
-    lists = jnp.where(lists >= 0, lists, dummy)
-    lists = jnp.pad(lists, ((0, 0), (0, ntile * TB - nbox), (0, S_pad - S)),
-                    constant_values=dummy)
-    return lists, prefetch_row_specs(TB, SW, width), ntile
-
-
-def staged_multilist(lists_seq, dummy: int, TB: int, SW: int):
-    """Concatenate several interaction lists along the slot axis for one
-    fused batch-major grid: each (B, nbox, S_k) region is
-    dummy-redirected and padded to a multiple of ``SW`` so it owns a
-    whole number of grid steps; the combined list is box-padded for the
-    TB-tile grid.
-
-    Returns ``(combined, ntile, region_steps)`` where ``region_steps[k]``
-    is the number of SW-wide grid steps of region k — the kernel branches
-    on the step axis ``pl.program_id(2)`` against the running step
-    offsets to know which interaction type a step carries.
-    """
-    nbox = lists_seq[0].shape[-2]
-    ntile = -(-nbox // TB)
+    B, nbox = lists_seq[0].shape[:2]
     regions, steps = [], []
     for lists in lists_seq:
         S = lists.shape[-1]
@@ -159,18 +188,32 @@ def staged_multilist(lists_seq, dummy: int, TB: int, SW: int):
                     constant_values=dummy)
         regions.append(l)
         steps.append(S_pad // SW)
-    combined = pad_boxes(jnp.concatenate(regions, axis=-1), ntile * TB,
-                         dummy)
-    return combined, ntile, steps
+    cols = sum(steps) * SW
+    ntile = -(-nbox // TB)
+    tile_bytes = 4 * B * TB * round_up(cols, 128)
+    per = max(1, min(ntile, SMEM_LIST_BYTES // tile_bytes))
+    nchunk = -(-ntile // per)
+    combined = pad_boxes(jnp.concatenate(regions, axis=-1),
+                         nchunk * per * TB, dummy)
+    return combined, nchunk, steps
 
 
-def compiler_params(**kwargs):
-    """TPU compiler params across jax versions (CompilerParams was named
-    TPUCompilerParams before jax 0.5)."""
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+def run_chunked(launch, nchunk: int, operands):
+    """``launch(*chunk)`` over ``nchunk`` equal slices of the box axis
+    (axis 1) of every operand, in sequence; the outputs' chunks are
+    stitched back along axis 1. ``operands`` lead with the staged list,
+    so each launch scalar-prefetches only its own chunk of it."""
+    def split(a):
+        B, rows = a.shape[:2]
+        return jnp.moveaxis(
+            a.reshape(B, nchunk, rows // nchunk, *a.shape[2:]), 1, 0)
+
+    def merge(a):
+        n, B, rows = a.shape[:3]
+        return jnp.moveaxis(a, 0, 1).reshape(B, n * rows, *a.shape[3:])
+
+    outs = jax.lax.map(lambda xs: launch(*xs), [split(a) for a in operands])
+    return [merge(o) for o in outs]
 
 
 def round_up(x: int, m: int) -> int:

@@ -21,7 +21,7 @@ exactly once:
                          against staged (1, P) multipole rows of the
                          (s - p2p_steps)-th m2p-list slot.
 
-Both lists ride in ONE scalar-prefetch operand (``staged_multilist``):
+Both lists ride in ONE scalar-prefetch operand (``staged_lists``):
 the p2p region's columns select particle rows, the m2p region's columns
 select multipole rows. Every staged spec family DMAs on every step — in
 the foreign region it fetches a (harmless, valid) row that the
@@ -46,9 +46,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common import (broadcast_unbatched, compiler_params, l2p_horner,
+from ..common import (ZERO, broadcast_unbatched, l2p_horner,
                       pad_boxes, pairwise_tile, prefetch_row_specs,
-                      resolve_interpret, staged_multilist)
+                      resolve_interpret, row_view, run_chunked, slot_spec,
+                      slot_view, staged_lists)
 
 
 def _make_kernel(p: int, P: int, kernel: str, TB: int, SW: int,
@@ -152,30 +153,20 @@ def _eval_fused_pallas(p2p_lists, m2p_lists, tzr, tzi, trk, tr, ti, br, bi,
     P = br.shape[-1]
 
     regions = [p2p_lists] + ([m2p_lists] if with_m2p else [])
-    lists, ntile, steps = staged_multilist(regions, dummy, TB, SW)
+    lists, nchunk, steps = staged_lists(regions, dummy, TB, SW)
     p2p_steps = steps[0]
     m2p_steps = steps[1] if with_m2p else 0
+    rows = lists.shape[1]
 
     def tgt(a, fill=0):
-        return pad_boxes(a, ntile * TB, fill)
+        return pad_boxes(a, rows, fill)
 
-    tzr, tzi, tr, ti = tgt(tzr), tgt(tzi), tgt(tr), tgt(ti)
-    br, bi, trk = tgt(br), tgt(bi), tgt(trk, -1)
-
-    def tgt_map(b, i, s, lref):
-        return (b, i, 0)
-
-    def slot_map(b, i, s, lref):
-        return (b, i, s)
-
-    part_specs = prefetch_row_specs(TB, SW, n_pad)   # particle/rank rows
-    in_specs = ([pl.BlockSpec((None, TB, n_pad), tgt_map)] * 5
-                + [pl.BlockSpec((None, TB, P), tgt_map)] * 2
-                + part_specs * 5)
+    tiled = [lists, tgt(tzr), tgt(tzi), tgt(trk, -1), tgt(tr), tgt(ti),
+             tgt(br), tgt(bi)]
     n = TB * SW
-    operands = [lists, tzr, tzi, trk, tr, ti, br, bi,
-                *([szr] * n), *([szi] * n), *([sqr] * n), *([sqi] * n),
-                *([srk] * n)]
+    szr, szi, sqr, sqi, srk = map(row_view, (szr, szi, sqr, sqi, srk))
+    shared = [*([szr] * n), *([szi] * n), *([sqr] * n), *([sqi] * n),
+              *([srk] * n)]
     if with_m2p:
         # slot planes span the whole fused list (zeros in the p2p region)
         total_cols = (p2p_steps + m2p_steps) * SW
@@ -186,31 +177,45 @@ def _eval_fused_pallas(p2p_lists, m2p_lists, tzr, tzi, trk, tr, ti, br, bi,
                              total_cols - p2p_steps * SW - a.shape[-1])))
             return tgt(a)
 
-        mult_specs = prefetch_row_specs(TB, SW, P)   # multipole rows
-        in_specs += (mult_specs * 2
-                     + [pl.BlockSpec((None, TB, SW), slot_map)] * 3)
-        operands += [*([ar] * n), *([ai] * n),
-                     slot_plane(mcr), slot_plane(mci), slot_plane(mrho)]
+        tiled += [slot_plane(mcr), slot_plane(mci), slot_plane(mrho)]
+        ar, ai = row_view(ar), row_view(ai)
+        shared += [*([ar] * n), *([ai] * n)]
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, ntile, p2p_steps + m2p_steps),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((None, TB, n_pad), tgt_map),
-            pl.BlockSpec((None, TB, n_pad), tgt_map),
-        ],
-    )
+    def tgt_map(b, i, s, lref):
+        return (b, i, ZERO)
+
+    part_specs = prefetch_row_specs(TB, SW, n_pad)   # particle/rank rows
+    in_specs = ([pl.BlockSpec((None, TB, n_pad), tgt_map)] * 5
+                + [pl.BlockSpec((None, TB, P), tgt_map)] * 2
+                + part_specs * 5)
+    if with_m2p:
+        in_specs += prefetch_row_specs(TB, SW, P) * 2    # multipole rows
+        in_specs += [slot_spec(TB, SW)] * 3
     dt = tzr.dtype
-    outr, outi = pl.pallas_call(
-        _make_kernel(p, P, kernel, TB, SW, p2p_steps, m2p_steps),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, ntile * TB, n_pad), dt)] * 2,
-        compiler_params=compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(*operands)
+
+    def launch(lists, *targets):
+        crows = lists.shape[1]
+        slots = [slot_view(a, SW) for a in targets[7:]]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, crows // TB, p2p_steps + m2p_steps),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((None, TB, n_pad), tgt_map),
+                pl.BlockSpec((None, TB, n_pad), tgt_map),
+            ],
+        )
+        return pl.pallas_call(
+            _make_kernel(p, P, kernel, TB, SW, p2p_steps, m2p_steps),
+            grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct((B, crows, n_pad), dt)] * 2,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+            ),
+            interpret=interpret,
+        )(lists, *targets[:7], *shared, *slots)
+
+    outr, outi = run_chunked(launch, nchunk, tiled)
     return outr[:, :nbox], outi[:, :nbox]
 
 

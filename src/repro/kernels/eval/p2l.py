@@ -33,8 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common import (compiler_params, make_batched_op, pad_boxes,
-                      resolve_interpret, staged_list_specs)
+from ..common import (ZERO, make_batched_op, pad_boxes, prefetch_row_specs,
+                      resolve_interpret, row_view, run_chunked, staged_lists)
 
 
 def _make_kernel(p: int, P: int, kernel: str, TB: int, SW: int):
@@ -127,37 +127,46 @@ def _p2l_pallas(lists, z0r, z0i, rho, xzr, xzi, xqr, xqi, *, p: int, P: int,
     TB, SW = tile_boxes, stage_width
     dummy = xzr.shape[-2] - 1
 
-    lists, src_specs, ntile = staged_list_specs(lists, dummy, TB, SW, n_pad)
+    lists, nchunk, (steps,) = staged_lists([lists], dummy, TB, SW)
+    rows = lists.shape[1]
 
     def col(a):
-        return pad_boxes(a.reshape(B, -1, 1), ntile * TB)
+        return pad_boxes(a.reshape(B, -1, 1), rows)
 
-    z0r, z0i, rho = col(z0r), col(z0i), col(rho)
+    n = TB * SW
+    xzr, xzi, xqr, xqi = map(row_view, (xzr, xzi, xqr, xqi))
+    shared = [*([xzr] * n), *([xzi] * n), *([xqr] * n), *([xqi] * n)]
 
     def tgt_map(b, i, s, lref):
-        return (b, i, 0)
+        return (b, i, ZERO)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, ntile, lists.shape[-1] // SW),
-        in_specs=[pl.BlockSpec((None, TB, 1), tgt_map)] * 3 + src_specs * 4,
-        out_specs=[
-            pl.BlockSpec((None, TB, P), tgt_map),
-            pl.BlockSpec((None, TB, P), tgt_map),
-        ],
-    )
+    in_specs = ([pl.BlockSpec((None, TB, 1), tgt_map)] * 3
+                + prefetch_row_specs(TB, SW, n_pad) * 4)
     dt = xzr.dtype
-    n = TB * SW
-    outr, outi = pl.pallas_call(
-        _make_kernel(p, P, kernel, TB, SW),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, ntile * TB, P), dt)] * 2,
-        compiler_params=compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(lists, z0r, z0i, rho, *([xzr] * n), *([xzi] * n), *([xqr] * n),
-      *([xqi] * n))
+
+    def launch(lists, z0r, z0i, rho):
+        crows = lists.shape[1]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, crows // TB, steps),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((None, TB, P), tgt_map),
+                pl.BlockSpec((None, TB, P), tgt_map),
+            ],
+        )
+        return pl.pallas_call(
+            _make_kernel(p, P, kernel, TB, SW),
+            grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct((B, crows, P), dt)] * 2,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+            ),
+            interpret=interpret,
+        )(lists, z0r, z0i, rho, *shared)
+
+    outr, outi = run_chunked(launch, nchunk,
+                             [lists, col(z0r), col(z0i), col(rho)])
     return outr[:, :nbox], outi[:, :nbox]
 
 

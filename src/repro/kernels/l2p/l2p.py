@@ -16,8 +16,9 @@ import functools
 
 import jax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..common import (compiler_params, l2p_horner, make_batched_op,
+from ..common import (ZERO, l2p_horner, make_batched_op,
                       pad_boxes, resolve_interpret)
 
 
@@ -40,7 +41,7 @@ def _l2p_pallas(br, bi, tr, ti, *, p: int, tile_boxes: int, interpret: bool):
     tr, ti = pad_boxes(tr, ntile * TB), pad_boxes(ti, ntile * TB)
 
     def row(b, i):
-        return (b, i, 0)
+        return (b, i, ZERO)
 
     dt = tr.dtype
     outr, outi = pl.pallas_call(
@@ -57,7 +58,7 @@ def _l2p_pallas(br, bi, tr, ti, *, p: int, tile_boxes: int, interpret: bool):
             pl.BlockSpec((None, TB, n_pad), row),
         ],
         out_shape=[jax.ShapeDtypeStruct((B, ntile * TB, n_pad), dt)] * 2,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

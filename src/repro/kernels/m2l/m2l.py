@@ -39,8 +39,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common import (broadcast_unbatched, compiler_params, pad_boxes,
-                      resolve_interpret, round_up, staged_list_specs)
+from ..common import (ZERO, broadcast_unbatched, prefetch_row_specs,
+                      resolve_interpret, row_view, run_chunked, slot_spec,
+                      slot_view, staged_lists)
 
 
 def _make_kernel(p: int, P: int, kernel: str, TB: int, SW: int):
@@ -85,8 +86,12 @@ def _make_kernel(p: int, P: int, kernel: str, TB: int, SW: int):
             ahr = ar * pr - ai * pi
             ahi = ar * pi + ai * pr
             dt = ar.dtype
-            bhr = jnp.dot(ahr, ht, preferred_element_type=dt)
-            bhi = jnp.dot(ahi, ht, preferred_element_type=dt)
+            # f32 contraction: Mosaic's default precision rounds the
+            # operands to bf16, ~1e-2 pointwise FMM error at p = 17
+            bhr = jnp.dot(ahr, ht, preferred_element_type=dt,
+                          precision=jax.lax.Precision.HIGHEST)
+            bhi = jnp.dot(ahi, ht, preferred_element_type=dt,
+                          precision=jax.lax.Precision.HIGHEST)
             outr[...] += bhr * mr - bhi * mi
             outi[...] += bhr * mi + bhi * mr
             if kernel == "log":
@@ -111,50 +116,53 @@ def _m2l_pallas(weak: jax.Array, ar, ai, prer, prei, postr, posti, logr,
     B, nbox, W = weak.shape
     P = ar.shape[-1]
     TB, SW = tile_boxes, stage_width
-    W_pad = round_up(W, SW)
     dummy = ar.shape[-2] - 1
 
-    weak, src_specs, ntile = staged_list_specs(weak, dummy, TB, SW, P)
+    weak, nchunk, (steps,) = staged_lists([weak], dummy, TB, SW)
+    rows, W_pad = weak.shape[1:]
 
     def plane(a):
-        a = pad_boxes(a, ntile * TB)
-        return jnp.pad(a, ((0, 0), (0, 0), (0, W_pad - W)))
+        return jnp.pad(a, ((0, 0), (0, rows - nbox), (0, W_pad - W)))
 
     planes = [plane(a) for a in (prer, prei, postr, posti)]
     if kernel == "log":
         planes += [plane(logr), plane(logi)]
+    n = TB * SW
+    rows_ar = [row_view(ar)] * n + [row_view(ai)] * n
 
     def tgt_map(b, i, s, wref):
-        return (b, i, 0)
-
-    def slot_map(b, i, s, wref):
-        return (b, i, s)
+        return (b, i, ZERO)
 
     def const_map(b, i, s, wref):
-        return (0, 0)
+        return (ZERO, ZERO)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, ntile, W_pad // SW),
-        in_specs=(src_specs * 2
-                  + [pl.BlockSpec((None, TB, SW), slot_map)] * len(planes)
-                  + [pl.BlockSpec((P, P), const_map)]),
-        out_specs=[
-            pl.BlockSpec((None, TB, P), tgt_map),
-            pl.BlockSpec((None, TB, P), tgt_map),
-        ],
-    )
+    in_specs = (prefetch_row_specs(TB, SW, P) * 2
+                + [slot_spec(TB, SW)] * len(planes)
+                + [pl.BlockSpec((P, P), const_map)])
     dt = ar.dtype
-    n = TB * SW
-    outr, outi = pl.pallas_call(
-        _make_kernel(p, P, kernel, TB, SW),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, ntile * TB, P), dt)] * 2,
-        compiler_params=compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(weak, *([ar] * n), *([ai] * n), *planes, ht)
+
+    def launch(weak, *planes):
+        crows = weak.shape[1]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, crows // TB, steps),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((None, TB, P), tgt_map),
+                pl.BlockSpec((None, TB, P), tgt_map),
+            ],
+        )
+        return pl.pallas_call(
+            _make_kernel(p, P, kernel, TB, SW),
+            grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct((B, crows, P), dt)] * 2,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+            ),
+            interpret=interpret,
+        )(weak, *rows_ar, *(slot_view(a, SW) for a in planes), ht)
+
+    outr, outi = run_chunked(launch, nchunk, [weak, *planes])
     return outr[:, :nbox], outi[:, :nbox]
 
 

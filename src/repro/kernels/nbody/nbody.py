@@ -14,7 +14,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from ..common import compiler_params, resolve_interpret
+from jax.experimental.pallas import tpu as pltpu
+
+from ..common import ZERO, resolve_interpret
 
 
 def _nbody_kernel(tzr, tzi, szr, szi, sqr, sqi, outr, outi):
@@ -43,10 +45,10 @@ def _nbody_pallas(tzr, tzi, szr, szi, sqr, sqi, *, t_tile: int,
     ns = szr.shape[0] // s_tile
 
     def tmap(i, j):
-        return (i, 0)
+        return (i, ZERO)
 
     def smap(i, j):
-        return (j, 0)
+        return (j, ZERO)
 
     dt = tzr.dtype
     r2 = lambda a, n: a.reshape(-1, n)
@@ -66,7 +68,7 @@ def _nbody_pallas(tzr, tzi, szr, szi, sqr, sqi, *, t_tile: int,
             pl.BlockSpec((1, t_tile), tmap),
         ],
         out_shape=[jax.ShapeDtypeStruct((nt, t_tile), dt)] * 2,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

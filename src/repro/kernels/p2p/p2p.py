@@ -31,8 +31,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common import (compiler_params, make_batched_op, pad_boxes,
-                      pairwise_tile, resolve_interpret, staged_list_specs)
+from ..common import (ZERO, make_batched_op, pad_boxes, pairwise_tile,
+                      prefetch_row_specs, resolve_interpret, row_view,
+                      run_chunked, staged_lists)
 
 
 def _make_kernel(kernel: str, TB: int, SW: int):
@@ -80,37 +81,44 @@ def _p2p_pallas(lists: jax.Array, tzr, tzi, trk, szr, szi, sqr, sqi, srk, *,
     TB, SW = tile_boxes, stage_width
     dummy = szr.shape[-2] - 1  # index of the all-zero row
 
-    lists, src_specs, ntile = staged_list_specs(lists, dummy, TB, SW, n_pad)
-    tzr = pad_boxes(tzr, ntile * TB)
-    tzi = pad_boxes(tzi, ntile * TB)
-    trk = pad_boxes(trk, ntile * TB, -1)
+    lists, nchunk, (steps,) = staged_lists([lists], dummy, TB, SW)
+    rows = lists.shape[1]
+    n = TB * SW
+    szr, szi, sqr, sqi, srk = map(row_view, (szr, szi, sqr, sqi, srk))
+    shared = [*([szr] * n), *([szi] * n), *([sqr] * n), *([sqi] * n),
+              *([srk] * n)]
 
     def tgt_map(b, i, s, lref):
-        return (b, i, 0)
+        return (b, i, ZERO)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, ntile, lists.shape[-1] // SW),
-        in_specs=[pl.BlockSpec((None, TB, n_pad), tgt_map),
-                  pl.BlockSpec((None, TB, n_pad), tgt_map),
-                  pl.BlockSpec((None, TB, n_pad), tgt_map)] + src_specs * 5,
-        out_specs=[
-            pl.BlockSpec((None, TB, n_pad), tgt_map),
-            pl.BlockSpec((None, TB, n_pad), tgt_map),
-        ],
-    )
+    in_specs = ([pl.BlockSpec((None, TB, n_pad), tgt_map)] * 3
+                + prefetch_row_specs(TB, SW, n_pad) * 5)
     dt = tzr.dtype
-    n = TB * SW
-    outr, outi = pl.pallas_call(
-        _make_kernel(kernel, TB, SW),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, ntile * TB, n_pad), dt)] * 2,
-        compiler_params=compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(lists, tzr, tzi, trk, *([szr] * n), *([szi] * n), *([sqr] * n),
-      *([sqi] * n), *([srk] * n))
+
+    def launch(lists, tzr, tzi, trk):
+        crows = lists.shape[1]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, crows // TB, steps),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((None, TB, n_pad), tgt_map),
+                pl.BlockSpec((None, TB, n_pad), tgt_map),
+            ],
+        )
+        return pl.pallas_call(
+            _make_kernel(kernel, TB, SW),
+            grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct((B, crows, n_pad), dt)] * 2,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+            ),
+            interpret=interpret,
+        )(lists, tzr, tzi, trk, *shared)
+
+    outr, outi = run_chunked(launch, nchunk, [
+        lists, pad_boxes(tzr, rows), pad_boxes(tzi, rows),
+        pad_boxes(trk, rows, -1)])
     return outr[:, :nbox], outi[:, :nbox]
 
 

@@ -2,25 +2,19 @@
 
 The leaf level holds 3/4 of all boxes, so its classification dominates
 the connect phase. One grid step classifies a ``tile_boxes`` tile of
-target boxes against their full (4S-wide) candidate row: the (1, nbox)
-center/radius planes of the leaf level stay VMEM-resident across the
-whole grid (a few KB — leaf counts are 4**L), candidate geometry is
-gathered from them in-register, and the kernel emits the five *keyed*
-arrays (strong, weak, p2p, p2l, m2p: kept entries carry the candidate
-id, dropped entries INT32_MAX) that ``build_connectivity`` feeds to its
-single batched compaction sort.
+target boxes against their full (4S-wide) candidate row and emits the
+five *keyed* arrays (strong, weak, p2p, p2l, m2p: kept entries carry the
+candidate id, dropped entries INT32_MAX) that ``build_connectivity``
+feeds to its single batched compaction sort.
 
-The elementwise predicates are the exact plane-form formulas of
-``core.topology.connectivity._theta_masks`` / ``_swapped_masks`` — the
-two paths must agree bit-for-bit, which the parity sweep in
-tests/test_topology.py checks on every distribution.
-
-NOTE on the in-kernel gather: candidate geometry is fetched with
-``jnp.take`` from the resident planes. Interpret mode (CPU, how this
-repo tests) executes it directly; on real TPUs Mosaic lowers last-dim
-dynamic gathers on newer toolchains only — if a target toolchain
-rejects it, stage per-slot rows through scalar prefetch like the P2P
-kernel instead.
+Candidate geometry (center and radius of every candidate box) is
+gathered by XLA before the launch, with the reference path's own
+``_gather_geometry``, and streamed in as (TB, Cp) planes beside the
+candidate ids: Mosaic lowers no general in-kernel gather, so the kernel
+body stays purely elementwise. The predicates are the exact plane-form
+formulas of ``core.topology.connectivity._theta_masks`` /
+``_swapped_masks`` — the two paths must agree bit-for-bit, which the
+parity sweep in tests/test_topology.py checks on every distribution.
 """
 from __future__ import annotations
 
@@ -30,26 +24,22 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..common import compiler_params, pad_rows, resolve_interpret, round_up
+from ...core.topology.connectivity import _gather_geometry
+from ..common import ZERO, pad_rows, resolve_interpret, round_up
 
 _INT_MAX = np.int32(np.iinfo(np.int32).max)
 
 
 def _make_kernel(theta: float, use_p2l_m2p: bool):
-    def body(cand_ref, tbx_ref, tby_ref, tbr_ref, cxf_ref, cyf_ref, rf_ref,
+    def body(cand_ref, ccx_ref, ccy_ref, rc_ref, tbx_ref, tby_ref, tbr_ref,
              ks_ref, kw_ref, kp_ref, kl_ref, km_ref):
         cand = cand_ref[...]                      # (TB, Cp) int32, -1 invalid
         valid = cand >= 0
-        dummy = cxf_ref.shape[1] - 1              # zeroed trailing plane slot
-        idx = jnp.where(valid, cand, dummy)
-        ccx = jnp.take(cxf_ref[0, :], idx)        # (TB, Cp) candidate geometry
-        ccy = jnp.take(cyf_ref[0, :], idx)
-        rc = jnp.take(rf_ref[0, :], idx)
-        ccx = jnp.where(valid, ccx, 0.0)
-        ccy = jnp.where(valid, ccy, 0.0)
-        rc = jnp.where(valid, rc, 0.0)
-
+        ccx = ccx_ref[...]                        # (TB, Cp) candidate geometry
+        ccy = ccy_ref[...]
+        rc = rc_ref[...]
         tbx = tbx_ref[...]                        # (TB, 1) target geometry
         tby = tby_ref[...]
         rb = tbr_ref[...]
@@ -82,35 +72,34 @@ def _make_kernel(theta: float, use_p2l_m2p: bool):
 
 @functools.partial(jax.jit, static_argnames=("theta", "use_p2l_m2p",
                                              "tile_boxes", "interpret"))
-def _classify_pallas(cand, tbx, tby, tbr, cxf, cyf, rf, *, theta: float,
+def _classify_pallas(cand, ccx, ccy, rc, tbx, tby, tbr, *, theta: float,
                      use_p2l_m2p: bool, tile_boxes: int, interpret: bool):
     nb, C = cand.shape
     TB = tile_boxes
     ntile = -(-nb // TB)
     Cp = round_up(C, 128)
-    cand = pad_rows(jnp.pad(cand, ((0, 0), (0, Cp - C)), constant_values=-1),
-                    ntile * TB, -1)
+
+    def row(a, fill=0):
+        a = jnp.pad(a, ((0, 0), (0, Cp - C)), constant_values=fill)
+        return pad_rows(a, ntile * TB, fill)
 
     def col(a):
         return pad_rows(a.reshape(-1, 1), ntile * TB)
 
     def tgt_map(i):
-        return (i, 0)
-
-    def full_map(i):
-        return (0, 0)
+        return (i, ZERO)
 
     outs = pl.pallas_call(
         _make_kernel(theta, use_p2l_m2p),
         grid=(ntile,),
-        in_specs=[pl.BlockSpec((TB, Cp), tgt_map)]
-        + [pl.BlockSpec((TB, 1), tgt_map)] * 3
-        + [pl.BlockSpec((1, cxf.shape[1]), full_map)] * 3,
+        in_specs=[pl.BlockSpec((TB, Cp), tgt_map)] * 4
+        + [pl.BlockSpec((TB, 1), tgt_map)] * 3,
         out_specs=[pl.BlockSpec((TB, Cp), tgt_map)] * 5,
         out_shape=[jax.ShapeDtypeStruct((ntile * TB, Cp), jnp.int32)] * 5,
-        compiler_params=compiler_params(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(cand, col(tbx), col(tby), col(tbr), cxf, cyf, rf)
+    )(row(cand, -1), row(ccx), row(ccy), row(rc), col(tbx), col(tby),
+      col(tbr))
     return tuple(o[:nb, :C] for o in outs)
 
 
@@ -121,22 +110,16 @@ def leaf_classify_pallas(cand, valid, centers, radii, cfg,
 
     ``cand``/``valid``: (4**L, 4S) candidates; ``centers``/``radii``: the
     leaf-level box geometry. Returns the five keyed (4**L, 4S) int32
-    arrays. ``interpret=None`` auto-selects from the JAX platform.
+    arrays. The kernel tile is ``tile_boxes`` rounded up to the 8-row
+    sublane tile, so every block is aligned for Mosaic.
+    ``interpret=None`` auto-selects from the JAX platform.
     """
     rdt = cfg.real_dtype
-    nb = centers.shape[0]
-    nbp = round_up(nb + 1, 128)
-
-    def plane(a):
-        return jnp.pad(a.astype(rdt), (0, nbp - nb)).reshape(1, nbp)
-
-    cxf, cyf = plane(jnp.real(centers)), plane(jnp.imag(centers))
-    rf = plane(radii)
-    tbx = jnp.real(centers).astype(rdt)
-    tby = jnp.imag(centers).astype(rdt)
-    tbr = radii.astype(rdt)
+    ccx, ccy, rc = _gather_geometry(cand, valid, centers, radii)
     cand = jnp.where(valid, cand, -1).astype(jnp.int32)
-    return _classify_pallas(cand, tbx, tby, tbr, cxf, cyf, rf,
+    return _classify_pallas(cand, ccx.astype(rdt), ccy.astype(rdt),
+                            rc.astype(rdt), jnp.real(centers).astype(rdt),
+                            jnp.imag(centers).astype(rdt), radii.astype(rdt),
                             theta=cfg.theta, use_p2l_m2p=cfg.use_p2l_m2p,
-                            tile_boxes=cfg.tile_boxes,
+                            tile_boxes=round_up(cfg.tile_boxes, 8),
                             interpret=resolve_interpret(interpret))

@@ -21,18 +21,11 @@ from jax.sharding import PartitionSpec as PS
 
 
 def _shard_map(f, mesh, in_specs, out_specs, manual_axes):
-    """shard_map manual over ``manual_axes`` only, across jax versions
-    (jax.shard_map/axis_names/check_vma landed in 0.5; 0.4 spells it
-    experimental shard_map with auto= the complement and check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             axis_names=frozenset(manual_axes),
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    auto = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False, auto=auto)
+    """shard_map manual over ``manual_axes`` only; the remaining mesh
+    axes stay auto-partitioned."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs,
+                         axis_names=frozenset(manual_axes), check_vma=False)
 
 
 def quantize_int8(x):

@@ -57,15 +57,9 @@ ACT_DP = ("pod", "data")   # data axes for activation batch dims
 
 
 def active_mesh():
-    """The mesh whose axes sharding constraints may reference, or None.
-
-    Version compat: jax >= 0.5 exposes the (abstract) mesh context via
-    jax.sharding.get_abstract_mesh(); on jax < 0.5 the ``with mesh:``
-    context lives in thread_resources."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    from jax._src.mesh import thread_resources
-    return thread_resources.env.physical_mesh
+    """The (abstract) context mesh whose axes sharding constraints may
+    reference."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def maybe_shard(x, spec: PS):

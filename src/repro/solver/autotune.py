@@ -21,29 +21,32 @@ one evaluation) a handful of times on a sample of the workload:
 
 ``tune_tiles`` picks the Pallas kernel tiling (``tile_boxes`` /
 ``stage_width``, DESIGN.md §2) for the tuned caps: a timing sweep of the
-real end-to-end apply path when the backend compiles (on TPU), a
-lane-geometry heuristic otherwise (interpret-mode timings are noise).
+evaluation (upward, downward, evaluation — the phases the tiles drive)
+on one plan built for the whole sweep, when the backend compiles (on
+TPU); a lane-geometry heuristic otherwise (interpret-mode timings are
+noise). Both tuners share one compiled tree build per (N, depth, dtype).
 
 A 2-D sample ``(B, N)`` tunes a shared cap budget across all B problems
 (the ``apply_batched`` serving shape): caps are sized to the worst row.
 On a backend that serves batches through its own hooks (the batched-
 dispatch contract, ``repro.solver.backends``) the tile sweep then times
-the *batched* apply path — the batch-major kernel grids are what
+the *batched* evaluation — the batch-major kernel grids are what
 production serves, and the best tile can differ once B problems share
 the launch — while a "fallback" backend times one row as before.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
+from ..core import fmm as _fmm
 from ..core.config import FmmConfig, max_leaf_size
-from ..core.fmm import fmm_build
-from ..core.topology import connectivity_stats
+from ..core.topology import build_tree, connectivity_stats
 from ..kernels.common import default_interpret
 from .backends import get_backend
 
@@ -64,6 +67,22 @@ def _round_up(x: int, m: int) -> int:
     return max(m, (x + m - 1) // m * m)
 
 
+@functools.lru_cache(maxsize=8)
+def _tree_builder(n: int, nlevels: int, dtype: str):
+    """``build_tree`` compiled once per (n, nlevels, dtype): the tree does
+    not depend on the caps or tiles a tuning run varies, so every trial
+    shares one compiled sort (minutes of TPU compile at N ~ 1e6)."""
+    cfg = FmmConfig(n=n, nlevels=nlevels, dtype=dtype)
+    return jax.jit(lambda z, q: build_tree(z, q, cfg))
+
+
+def _build_plan(z: jax.Array, q: jax.Array, cfg: FmmConfig) -> _fmm.FmmPlan:
+    """``fmm_build`` for tuning: the shared compiled tree, then the
+    (cap-dependent, cheap) connectivity run eagerly."""
+    tree = _tree_builder(cfg.n, cfg.nlevels, cfg.dtype)(z, q)
+    return _fmm.FmmPlan(tree=tree, conn=_fmm.build_connectivity(tree, cfg))
+
+
 def probe_caps(z: jax.Array, q: jax.Array, cfg: FmmConfig) -> tuple[int, dict]:
     """Build tree+connectivity once; return (overflow, stats).
 
@@ -74,8 +93,7 @@ def probe_caps(z: jax.Array, q: jax.Array, cfg: FmmConfig) -> tuple[int, dict]:
         z, q = z[None], q[None]
     overflow, stats = 0, None
     for b in range(z.shape[0]):
-        plan = fmm_build(z[b], q[b], cfg)
-        s = connectivity_stats(plan.conn)
+        s = connectivity_stats(_build_plan(z[b], q[b], cfg).conn)
         overflow = max(overflow, s["overflow"])
         if stats is None:
             stats = s
@@ -165,49 +183,75 @@ def eval_fused_vmem_bytes(cfg: FmmConfig, tile_boxes: int | None = None,
     return (resident + 2 * staged) * itemsize
 
 
+#: ``tile_boxes`` the sweep offers: whole multiples of the 8-row f32
+#: sublane tile. Mosaic refuses a (TB, width) target block with TB not a
+#: multiple of 8 (unless it spans the whole box axis), so smaller tiles
+#: compile only in interpret mode.
+TILE_CANDIDATES = (8, 16)
+
+
+#: Most source rows (``tile_boxes * stage_width``) one grid step may
+#: stage per plane family. At 64 (16 x 4) the TPU v5e compile of the
+#: N = 2**20 evaluation runs out of VMEM; 32 compiles.
+MAX_STAGED_ROWS = 32
+
+
 def tile_candidates(cfg: FmmConfig,
                     vmem_budget: int = EVAL_VMEM_BUDGET) -> list[int]:
-    """Pow-2 ``tile_boxes`` candidates up to the leaf-level box count,
-    filtered to tiles whose fused-evaluation working set fits the VMEM
-    budget (large-leaf configs cap the useful tile)."""
-    cands = [t for t in (1, 2, 4, 8, 16) if t <= cfg.nboxes] or [1]
+    """``TILE_CANDIDATES`` up to the leaf-level box count (the smallest
+    always stays), filtered to tiles whose fused-evaluation working set
+    fits the VMEM budget (large-leaf configs cap the useful tile)."""
+    cands = [t for t in TILE_CANDIDATES
+             if t <= max(cfg.nboxes, TILE_CANDIDATES[0])]
     fit = [t for t in cands
            if eval_fused_vmem_bytes(cfg, tile_boxes=t) <= vmem_budget]
     return fit or cands[:1]
 
 
 def heuristic_tiles(cfg: FmmConfig) -> FmmConfig:
-    """Lane-geometry default when timing is unavailable: the largest
-    pow-2 tile <= min(8 sublanes, nboxes) that keeps the fused evaluation
-    kernel inside the VMEM budget fills the f32 vector registers; one
-    staged slot keeps the working set minimal."""
-    tb = max(t for t in tile_candidates(cfg) if t <= 8)
+    """Lane-geometry default when timing is unavailable: the smallest
+    candidate tile (one 8-row sublane tile of boxes) fills the f32 vector
+    registers; one staged slot keeps the working set minimal."""
+    tb = tile_candidates(cfg)[0]
     return dataclasses.replace(cfg, tile_boxes=tb, stage_width=1)
 
 
-def _apply_timer(backend: str, repeats: int,
-                 batched: bool = False) -> Callable:
-    """Time the jitted end-to-end apply path for one config (seconds).
+def _evaluation_timer(backend: str, repeats: int,
+                      batched: bool = False) -> Callable:
+    """Time the jitted evaluation (upward, downward, evaluation) of one
+    plan per config, in seconds. The tiles drive the evaluation-phase
+    kernels; the plan (tree + connectivity, whose sort dominates compile
+    time) is built once and shared by every candidate.
 
     With ``batched=True`` the sample is (B, N) and the measured program
-    is ``jax.vmap`` of the pipeline — the batch-major kernel grids the
+    is ``jax.vmap`` of the evaluation — the batch-major kernel grids the
     serving entry point actually runs."""
-    from ..core.fmm import fmm_evaluate  # local: avoid cycle at import
+    plans: dict = {}
+
+    def plan_for(z, q, cfg: FmmConfig):
+        key = (cfg.strong_cap, cfg.weak_cap)
+        if key not in plans:
+            if batched:
+                rows = [_build_plan(z[b], q[b], cfg)
+                        for b in range(z.shape[0])]
+                plans[key] = jax.tree.map(lambda *a: jnp.stack(a), *rows)
+            else:
+                plans[key] = _build_plan(z, q, cfg)
+        return plans[key]
 
     def timer(z, q, cfg: FmmConfig) -> float:
-        be = get_backend(backend, cfg)
-        impls = be.phase_impls(cfg)
-        topo = be.topology_impls(cfg)
+        impls = get_backend(backend, cfg).phase_impls(cfg)
 
-        def one(z, q):
-            return fmm_evaluate(fmm_build(z, q, cfg, **topo), cfg, **impls)
+        def one(plan):
+            return _fmm.fmm_evaluate(plan, cfg, **impls)
 
         run = jax.jit(jax.vmap(one) if batched else one)
-        jax.block_until_ready(run(z, q))           # compile
+        plan = plan_for(z, q, cfg)
+        jax.block_until_ready(run(plan))           # compile
         best = float("inf")
         for _ in range(repeats):
             t0 = time.perf_counter()
-            jax.block_until_ready(run(z, q))
+            jax.block_until_ready(run(plan))
             best = min(best, time.perf_counter() - t0)
         return best
 
@@ -222,14 +266,14 @@ def tune_tiles(z: jax.Array, q: jax.Array | None, cfg: FmmConfig, *,
 
     When the resolved backend compiles Pallas kernels (pallas on a real
     TPU) — or a ``timer(z, q, cfg) -> seconds`` is injected — each
-    candidate is measured on the end-to-end apply path: first the
+    candidate is measured on the evaluation of one shared plan: first the
     ``tile_boxes`` sweep at ``stage_width=1``, then the stage-width sweep
     at the winning tile. Otherwise (reference backend, or interpret mode
     where timings are noise) a lane-geometry heuristic picks the tile.
 
     A (B, N) sample stays batched when the backend serves batches
     through its own hooks (``batched_dispatch`` != "fallback"): the
-    timer then measures the vmapped pipeline — i.e. the batch-major
+    timer then measures the vmapped evaluation — i.e. the batch-major
     kernel grids of ``apply_batched`` — so the tile is tuned for the
     shape production runs. On a "fallback" backend the sweep times one
     row, as the batched entry would not run these kernels anyway.
@@ -250,7 +294,7 @@ def tune_tiles(z: jax.Array, q: jax.Array | None, cfg: FmmConfig, *,
         z = z[0]
         q = None if q is None else jnp.asarray(q)[0]
     q = jnp.ones(z.shape, cfg.complex_dtype) if q is None else jnp.asarray(q)
-    timer = timer or _apply_timer(be.name, repeats, batched=batched)
+    timer = timer or _evaluation_timer(be.name, repeats, batched=batched)
 
     trials: list = []
 
@@ -266,8 +310,8 @@ def tune_tiles(z: jax.Array, q: jax.Array | None, cfg: FmmConfig, *,
                        if tb == best_tb and sw == 1)}
     for sw in (2, 4):
         # staged slots multiply the streamed rows: respect both the
-        # operand-count bound and the fused-eval VMEM budget
-        if (best_tb * sw <= 128
+        # staged-row bound and the fused-eval VMEM budget
+        if (best_tb * sw <= MAX_STAGED_ROWS
                 and eval_fused_vmem_bytes(cfg, best_tb, sw)
                 <= EVAL_VMEM_BUDGET):
             sw_times[sw] = measure(best_tb, sw)

@@ -48,6 +48,7 @@ from typing import Callable, Optional
 import jax
 
 from ..core.config import FmmConfig
+from ..errors import DTypeError
 
 # Hook signatures (matching repro.core.fmm.fmm_evaluate):
 #   p2p(tree, conn, cfg, idx)            -> (n,) complex contribution
@@ -137,7 +138,17 @@ def available_backends() -> list[str]:
 
 
 def get_backend(name: str, cfg: FmmConfig | None = None) -> Backend:
-    """Resolve a backend name ("auto" needs ``cfg`` to pick per-config)."""
+    """Resolve a backend name ("auto" needs ``cfg`` to pick per-config).
+
+    On a TPU, an f64 ``cfg`` for "pallas" or "auto" raises ``DTypeError``:
+    the kernels compute in f32 on the chip, which has no f64 vector unit
+    (XLA would have to emulate f64, and complex128 may not lower)."""
+    if (name in ("auto", "pallas") and cfg is not None
+            and cfg.dtype == "f64" and _platform() == "tpu"):
+        raise DTypeError(
+            f"dtype='f64' config with backend={name!r} on a TPU: the "
+            "Pallas kernels run f32 on the chip; build the config with "
+            "dtype='f32'")
     if name == "auto":
         return _resolve_auto(cfg)
     try:

@@ -1,19 +1,25 @@
 """Shared jaxpr-inspection helpers for the launch/sort-count tests."""
 
 
+def _sub_jaxprs(v):
+    """Jaxprs nested in one eqn param: a Jaxpr has ``.eqns``, a
+    ClosedJaxpr carries one under ``.jaxpr``."""
+    for sub in (v if isinstance(v, (list, tuple)) else [v]):
+        if hasattr(sub, "eqns"):
+            yield sub
+        elif hasattr(getattr(sub, "jaxpr", None), "eqns"):
+            yield sub.jaxpr
+
+
 def count_eqns(jaxpr, name: str) -> int:
     """Recursively count eqns of one primitive in a jaxpr (incl. sub-jaxprs)."""
-    from jax.core import Jaxpr, ClosedJaxpr
     n = 0
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == name:
             n += 1
         for v in eqn.params.values():
-            for sub in (v if isinstance(v, (list, tuple)) else [v]):
-                if isinstance(sub, ClosedJaxpr):
-                    n += count_eqns(sub.jaxpr, name)
-                elif isinstance(sub, Jaxpr):
-                    n += count_eqns(sub, name)
+            for sub in _sub_jaxprs(v):
+                n += count_eqns(sub, name)
     return n
 
 

@@ -110,15 +110,19 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.parallel import make_compressed_value_and_grad, init_pod_errors
 mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
 from jax.sharding import NamedSharding, PartitionSpec as PS
-w = jax.device_put(jnp.ones((8, 8)), NamedSharding(mesh, PS(None, "model")))
-batch = jax.device_put(jnp.arange(16.0).reshape(8, 2),
-                       NamedSharding(mesh, PS(("pod", "data"), None)))
-loss_fn = lambda p, b: jnp.mean((b @ p["w"][:2, :]) ** 2)
-vg = make_compressed_value_and_grad(loss_fn, mesh)
-errors = jax.device_put(init_pod_errors({"w": w}, 2),
-                        {"w": NamedSharding(mesh, PS("pod"))})
-loss, grads, errors = jax.jit(vg)({"w": w}, batch, errors)
-ref_loss, ref_g = jax.value_and_grad(loss_fn)({"w": w}, batch)
+# make_mesh axes are Explicit: computations on its arrays (the jit and
+# the eager reference alike) run under the mesh as context mesh
+with jax.set_mesh(mesh):
+    w = jax.device_put(jnp.ones((8, 8)),
+                       NamedSharding(mesh, PS(None, "model")))
+    batch = jax.device_put(jnp.arange(16.0).reshape(8, 2),
+                           NamedSharding(mesh, PS(("pod", "data"), None)))
+    loss_fn = lambda p, b: jnp.mean((b @ p["w"][:2, :]) ** 2)
+    vg = make_compressed_value_and_grad(loss_fn, mesh)
+    errors = jax.device_put(init_pod_errors({"w": w}, 2),
+                            {"w": NamedSharding(mesh, PS("pod"))})
+    loss, grads, errors = jax.jit(vg)({"w": w}, batch, errors)
+    ref_loss, ref_g = jax.value_and_grad(loss_fn)({"w": w}, batch)
 rel = np.abs(np.asarray(grads["w"]) - np.asarray(ref_g["w"])).max() / \
     np.abs(np.asarray(ref_g["w"])).max()
 assert rel < 0.02, rel

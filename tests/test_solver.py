@@ -1,5 +1,7 @@
 """FmmSolver front-end: plan caching, backend dispatch, batched
 evaluation vs a per-problem loop, and cap autotuning."""
+import dataclasses
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -68,7 +70,7 @@ def test_unknown_backend_raises():
 
 
 def test_pallas_backend_supports_log_kernel(monkeypatch):
-    cfg = FmmConfig(n=64, nlevels=1, p=6, kernel="log", dtype="f64")
+    cfg = FmmConfig(n=64, nlevels=1, p=6, kernel="log", dtype="f32")
     assert get_backend("pallas", cfg).supports(cfg)
     # "auto" must dispatch log-kernel configs somewhere that supports them
     assert get_backend("auto", cfg).supports(cfg)
@@ -79,6 +81,20 @@ def test_pallas_backend_supports_log_kernel(monkeypatch):
     assert get_backend("auto", cfg).name == "pallas"
     monkeypatch.setattr(backends, "_platform", lambda: "cpu")
     assert get_backend("auto", cfg).name == "reference"
+
+
+@pytest.mark.parametrize("backend", ["auto", "pallas"])
+def test_f64_config_on_tpu_raises_dtype_error_at_build(monkeypatch, backend):
+    """f64 has no kernel path on the chip: build refuses it with the typed
+    error naming the dtype (no compiler trace, no silent reference)."""
+    from repro.errors import DTypeError
+    from repro.solver import backends
+    monkeypatch.setattr(backends, "_platform", lambda: "tpu")
+    cfg = FmmConfig(n=64, nlevels=1, p=6, dtype="f64")
+    with pytest.raises(DTypeError, match="f64"):
+        FmmSolver.build(cfg, backend)
+    f32 = FmmConfig(n=64, nlevels=1, p=6, dtype="f32")
+    assert get_backend(backend, f32).name == "pallas"
 
 
 # ---------------------------------------------------------------------------
@@ -256,18 +272,19 @@ def test_tune_tiles_timing_sweep_picks_fastest():
 
     def timer(z, q, cfg):
         measured.append((cfg.tile_boxes, cfg.stage_width))
-        # fastest at tile_boxes=4, stage_width=2
-        return (abs(cfg.tile_boxes - 4) + 1) * (1.5 - 0.5 *
-                                                (cfg.stage_width == 2))
+        # fastest at tile_boxes=16, stage_width=2
+        return (abs(cfg.tile_boxes - 16) + 1) * (1.5 - 0.5 *
+                                                 (cfg.stage_width == 2))
 
     solver = FmmSolver.build(CFG64, "reference")
     z, q = particles("normal", CFG64.n, 5)
     tuned = solver.tune(jnp.asarray(z), jnp.asarray(q), tile_timer=timer)
-    assert tuned.cfg.tile_boxes == 4
+    assert tuned.cfg.tile_boxes == 16
     assert tuned.cfg.stage_width == 2
     assert len(tuned.tune_result.tile_trials) == len(measured)
-    # the tile sweep ran at stage_width=1 over pow-2 candidates <= nboxes
-    assert {t for t, s in measured if s == 1} == {1, 2, 4, 8, 16}
+    # the tile sweep ran at stage_width=1 over the sublane-multiple
+    # candidates Mosaic compiles
+    assert {t for t, s in measured if s == 1} == {8, 16}
 
 
 def test_tune_tiles_batched_sample_times_batched_path():
@@ -287,20 +304,44 @@ def test_tune_tiles_batched_sample_times_batched_path():
     assert shapes and all(s == (3, CFG64.n) for s in shapes)
 
 
+@pytest.mark.parametrize("batched", [False, True])
+def test_default_tile_timer_times_evaluation_on_one_shared_plan(batched):
+    """The compiling-backend timer (exercised here on the reference
+    backend) returns seconds per candidate and builds the plan once for
+    the whole sweep: the tree is compiled once, not per tile."""
+    from repro.solver import autotune
+    timer = autotune._evaluation_timer("reference", repeats=1,
+                                       batched=batched)
+    if batched:
+        z, q = _batch(2, CFG64.n)
+    else:
+        z, q = (jnp.asarray(a) for a in particles("normal", CFG64.n, 5))
+    builds = []
+    real = autotune._build_plan
+    try:
+        autotune._build_plan = lambda *a: builds.append(1) or real(*a)
+        times = [timer(z, q, dataclasses.replace(CFG64, tile_boxes=tb))
+                 for tb in (8, 16)]
+    finally:
+        autotune._build_plan = real
+    assert all(t > 0 for t in times)
+    assert len(builds) == (2 if batched else 1)
+
+
 def test_tile_candidates_respect_fused_eval_vmem_budget():
     """Large-leaf configs must cap tile_boxes: the fused evaluation
     kernel's VMEM working set scales with tile_boxes * n_pad."""
     from repro.solver.autotune import eval_fused_vmem_bytes, tile_candidates
     big_leaves = FmmConfig(n=1 << 15, nlevels=2, p=10, dtype="f32")
-    tight = 1 << 20
+    tight = 2 << 20
     cands = tile_candidates(big_leaves, vmem_budget=tight)
     assert cands and max(cands) < 16
     assert all(eval_fused_vmem_bytes(big_leaves, tile_boxes=t) <= tight
                for t in cands)
     # the default budget always leaves at least one candidate
     assert tile_candidates(big_leaves)
-    # small-leaf configs keep the full pow-2 sweep
-    assert tile_candidates(CFG64) == [1, 2, 4, 8, 16]
+    # small-leaf configs keep the full sublane-multiple sweep
+    assert tile_candidates(CFG64) == [8, 16]
 
 
 def test_solver_stats_reports_overflow_scalar():
