@@ -11,8 +11,7 @@ from .topology import (Tree, build_tree, leaf_particle_index, leaf_ids,
                        Connectivity, MARGIN_CLASSES, build_connectivity,
                        connectivity_stats)
 from .fmm import (FmmPlan, Health, HEALTH_CLASSES, fmm_build, fmm_evaluate,
-                  fmm_potential, fmm_potential_checked,
-                  fmm_potential_with_stats, health_of, p2m,
+                  fmm_potential, fmm_potential_checked, health_of, p2m,
                   upward, downward, l2p)
 from .direct import direct_potential, direct_potential_numpy, rel_error_inf
 
@@ -22,7 +21,7 @@ __all__ = [
     "Connectivity", "MARGIN_CLASSES", "build_connectivity",
     "connectivity_stats",
     "FmmPlan", "Health", "HEALTH_CLASSES", "fmm_build", "fmm_evaluate",
-    "fmm_potential", "fmm_potential_checked", "fmm_potential_with_stats",
+    "fmm_potential", "fmm_potential_checked",
     "health_of", "p2m", "upward", "downward", "l2p",
     "direct_potential", "direct_potential_numpy", "rel_error_inf",
 ]

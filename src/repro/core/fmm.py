@@ -1,14 +1,18 @@
 """The adaptive FMM pipeline (paper §3.3) as a single jit-able function.
 
-Phases (paper naming):
-  topological: build_tree (sort) + build_connectivity (connect)
-  upward:      P2M (+ P2L) , M2M
-  downward:    M2L , L2L
-  evaluation:  L2P (+ M2P) , P2P
+Phases (paper naming), each traced under the ``jax.named_scope`` of the
+same name, with the sub-scopes in parentheses:
+  topology:    build_tree (sort) + build_connectivity (connect)
+  upward:      P2M (p2m) , M2M (m2m)
+  downward:    M2L (m2l) , L2L (l2l) , P2L (p2l)
+  evaluation:  L2P (l2p) + M2P (m2p) + P2P (p2p), or the fused kernel;
+               the scatter back to input order (unsort)
 
-The per-phase functions are exposed individually so the benchmark harness
-can time them (Table 5.1 / Figs 5.1, 5.3, 5.7) and so the Pallas kernels
-in ``repro.kernels`` can replace the hot ones (P2P, M2L) one at a time.
+The scopes land in every device op's ``tf_op`` in a profiler trace
+(``jit(core)/upward/p2m/scatter-add``), so a trace times each phase (Table
+5.1 / Figs 5.1, 5.3, 5.7). The per-phase functions are exposed
+individually so the Pallas kernels in ``repro.kernels`` can replace the
+hot ones (P2P, M2L) one at a time.
 
 Every shape is static given ``FmmConfig``; there is no data-dependent
 control flow — the adaptivity lives entirely in the *contents* of the
@@ -145,9 +149,11 @@ def upward(tree: Tree, cfg: FmmConfig, rho=None) -> list[jax.Array]:
     if rho is None:
         rho = effective_radii(tree, cfg)
     m = [None] * (cfg.nlevels + 1)
-    m[cfg.nlevels] = p2m(tree, cfg, rho[cfg.nlevels])
-    for l in range(cfg.nlevels - 1, -1, -1):
-        m[l] = m2m_level(m[l + 1], tree, l, cfg, rho[l + 1], rho[l])
+    with jax.named_scope("p2m"):
+        m[cfg.nlevels] = p2m(tree, cfg, rho[cfg.nlevels])
+    with jax.named_scope("m2m"):
+        for l in range(cfg.nlevels - 1, -1, -1):
+            m[l] = m2m_level(m[l + 1], tree, l, cfg, rho[l + 1], rho[l])
     return m
 
 
@@ -243,10 +249,11 @@ def _apply_p2l(local, tree, conn, cfg: FmmConfig, rho, p2l_impl):
     if not (cfg.use_p2l_m2p and cfg.nlevels > 0):
         return local
     idx = leaf_particle_index(cfg)
-    if p2l_impl is None:
-        return p2l_sweep(local, tree, conn, cfg, jnp.asarray(idx),
-                         rho[cfg.nlevels])
-    return local + p2l_impl(tree, conn, cfg, idx, rho[cfg.nlevels])
+    with jax.named_scope("p2l"):
+        if p2l_impl is None:
+            return p2l_sweep(local, tree, conn, cfg, jnp.asarray(idx),
+                             rho[cfg.nlevels])
+        return local + p2l_impl(tree, conn, cfg, idx, rho[cfg.nlevels])
 
 
 def downward(mult: list[jax.Array], tree: Tree, conn: Connectivity,
@@ -260,12 +267,15 @@ def downward(mult: list[jax.Array], tree: Tree, conn: Connectivity,
 
     local = jnp.zeros((1, p + 1), dtype=cdt)
     for l in range(1, cfg.nlevels + 1):
-        local = l2l_level(local, tree, l, cfg, rho[l], rho[l - 1])
-        local = local + m2l_level(mult[l], conn.weak[l], tree.centers[l],
-                                  cfg, m2l_mat, rho[l])
+        with jax.named_scope("l2l"):
+            local = l2l_level(local, tree, l, cfg, rho[l], rho[l - 1])
+        with jax.named_scope("m2l"):
+            local = local + m2l_level(mult[l], conn.weak[l],
+                                      tree.centers[l], cfg, m2l_mat, rho[l])
     if cfg.nlevels == 0:
-        local = local + m2l_level(mult[0], conn.weak[0], tree.centers[0],
-                                  cfg, m2l_mat, rho[0])
+        with jax.named_scope("m2l"):
+            local = local + m2l_level(mult[0], conn.weak[0],
+                                      tree.centers[0], cfg, m2l_mat, rho[0])
     return _apply_p2l(local, tree, conn, cfg, rho, p2l_impl)
 
 
@@ -365,8 +375,12 @@ def fmm_build(z: jax.Array, q: jax.Array, cfg: FmmConfig,
     ``leaf_classify_impl`` optionally replaces the leaf-level
     strong/weak/swapped-theta classification (the ``Backend.leaf_classify``
     topology hook — the Pallas kernel on the pallas backend)."""
-    tree = build_tree(z, q, cfg)
-    conn = build_connectivity(tree, cfg, leaf_classify_impl=leaf_classify_impl)
+    with jax.named_scope("topology"):
+        with jax.named_scope("sort"):
+            tree = build_tree(z, q, cfg)
+        with jax.named_scope("connect"):
+            conn = build_connectivity(tree, cfg,
+                                      leaf_classify_impl=leaf_classify_impl)
     return FmmPlan(tree=tree, conn=conn)
 
 
@@ -388,34 +402,36 @@ def fmm_evaluate(plan: FmmPlan, cfg: FmmConfig,
     ``eval_fused_impl(local, mult_leaf, tree, conn, cfg, idx) -> (n,)``.
     """
     tree, conn = plan.tree, plan.conn
-    mult = upward(tree, cfg)
+    with jax.named_scope("upward"):
+        mult = upward(tree, cfg)
 
-    if m2l_fused_impl is not None:
-        local = downward_fused(mult, tree, conn, cfg, m2l_fused_impl,
-                               p2l_impl)
-    elif m2l_impl is None:
-        local = downward(mult, tree, conn, cfg, p2l_impl=p2l_impl)
-    else:
-        local = downward_with(mult, tree, conn, cfg, m2l_impl, p2l_impl)
+    with jax.named_scope("downward"):
+        if m2l_fused_impl is not None:
+            local = downward_fused(mult, tree, conn, cfg, m2l_fused_impl,
+                                   p2l_impl)
+        elif m2l_impl is None:
+            local = downward(mult, tree, conn, cfg, p2l_impl=p2l_impl)
+        else:
+            local = downward_with(mult, tree, conn, cfg, m2l_impl, p2l_impl)
 
     # numpy constant (static layout): kernel wrappers derive shapes from it
     idx = leaf_particle_index(cfg)
-    if eval_fused_impl is not None:
-        return eval_fused_impl(local, mult[cfg.nlevels], tree, conn, cfg,
-                               idx)
-
-    if l2p_impl is None:
-        phi = l2p(local, tree, cfg)
-    else:
-        phi = l2p_impl(local, tree, cfg, idx)
-    if cfg.use_p2l_m2p:
-        phi = m2p_sweep(phi, mult[cfg.nlevels], tree, conn, cfg)
-
-    if p2p_impl is None:
-        phi = p2p_sweep(phi, tree, conn, cfg, jnp.asarray(idx))
-    else:
-        phi = phi + p2p_impl(tree, conn, cfg, idx)
-    return phi
+    with jax.named_scope("evaluation"):
+        if eval_fused_impl is not None:
+            return eval_fused_impl(local, mult[cfg.nlevels], tree, conn, cfg,
+                                   idx)
+        with jax.named_scope("l2p"):
+            if l2p_impl is None:
+                phi = l2p(local, tree, cfg)
+            else:
+                phi = l2p_impl(local, tree, cfg, idx)
+        if cfg.use_p2l_m2p:
+            with jax.named_scope("m2p"):
+                phi = m2p_sweep(phi, mult[cfg.nlevels], tree, conn, cfg)
+        with jax.named_scope("p2p"):
+            if p2p_impl is None:
+                return p2p_sweep(phi, tree, conn, cfg, jnp.asarray(idx))
+            return phi + p2p_impl(tree, conn, cfg, idx)
 
 
 def downward_with(mult, tree, conn, cfg, m2l_impl, p2l_impl=None) -> jax.Array:
@@ -423,12 +439,15 @@ def downward_with(mult, tree, conn, cfg, m2l_impl, p2l_impl=None) -> jax.Array:
     rho = effective_radii(tree, cfg)
     local = jnp.zeros((1, p + 1), dtype=mult[-1].dtype)
     for l in range(1, cfg.nlevels + 1):
-        local = l2l_level(local, tree, l, cfg, rho[l], rho[l - 1])
-        local = local + m2l_impl(mult[l], conn.weak[l], tree.centers[l],
-                                 cfg, rho[l])
+        with jax.named_scope("l2l"):
+            local = l2l_level(local, tree, l, cfg, rho[l], rho[l - 1])
+        with jax.named_scope("m2l"):
+            local = local + m2l_impl(mult[l], conn.weak[l], tree.centers[l],
+                                     cfg, rho[l])
     if cfg.nlevels == 0:
-        local = local + m2l_impl(mult[0], conn.weak[0], tree.centers[0],
-                                 cfg, rho[0])
+        with jax.named_scope("m2l"):
+            local = local + m2l_impl(mult[0], conn.weak[0], tree.centers[0],
+                                     cfg, rho[0])
     return _apply_p2l(local, tree, conn, cfg, rho, p2l_impl)
 
 
@@ -445,14 +464,16 @@ def downward_fused(mult, tree, conn, cfg, m2l_fused_impl,
     """
     p = cfg.p
     rho = effective_radii(tree, cfg)
-    contribs = m2l_fused_impl(mult, conn.weak, tree.centers, cfg, rho)
+    with jax.named_scope("m2l"):
+        contribs = m2l_fused_impl(mult, conn.weak, tree.centers, cfg, rho)
     local = jnp.zeros((1, p + 1), dtype=mult[-1].dtype)
     if cfg.nlevels == 0:
         local = local + contribs[0]
     else:
-        for l in range(1, cfg.nlevels + 1):
-            local = l2l_level(local, tree, l, cfg, rho[l], rho[l - 1])
-            local = local + contribs[l - 1]
+        with jax.named_scope("l2l"):
+            for l in range(1, cfg.nlevels + 1):
+                local = l2l_level(local, tree, l, cfg, rho[l], rho[l - 1])
+                local = local + contribs[l - 1]
     return _apply_p2l(local, tree, conn, cfg, rho, p2l_impl)
 
 
@@ -460,18 +481,14 @@ def downward_fused(mult, tree, conn, cfg, m2l_fused_impl,
 def fmm_potential(z: jax.Array, q: jax.Array, cfg: FmmConfig) -> jax.Array:
     """Phi(z_i) = sum_{j != i} G(z_i, x_j) for all input points (eq. 1.1)."""
     plan = fmm_build(z, q, cfg)
-    phi_sorted = fmm_evaluate(plan, cfg)
-    out = jnp.zeros_like(phi_sorted)
-    return out.at[plan.tree.perm].set(phi_sorted)
+    return unsort(fmm_evaluate(plan, cfg), plan.tree.perm)
 
 
-def fmm_potential_with_stats(z, q, cfg):
-    """Non-jit variant returning (phi, connectivity stats)."""
-    from .topology import connectivity_stats
-    plan = fmm_build(z, q, cfg)
-    phi_sorted = fmm_evaluate(plan, cfg)
-    phi = jnp.zeros_like(phi_sorted).at[plan.tree.perm].set(phi_sorted)
-    return phi, connectivity_stats(plan.conn)
+def unsort(phi_sorted: jax.Array, perm: jax.Array) -> jax.Array:
+    """Sorted (leaf-order) potentials back to the input order; the last
+    step of the evaluation phase."""
+    with jax.named_scope("evaluation"), jax.named_scope("unsort"):
+        return jnp.zeros_like(phi_sorted).at[perm].set(phi_sorted)
 
 
 def fmm_potential_checked(z, q, cfg: FmmConfig, max_grow: int = 3):
@@ -488,9 +505,7 @@ def fmm_potential_checked(z, q, cfg: FmmConfig, max_grow: int = 3):
     for _ in range(max_grow + 1):
         plan = fmm_build(z, q, cfg)
         if int(jax.device_get(plan.conn.overflow)) == 0:
-            phi_sorted = fmm_evaluate(plan, cfg)
-            out = jnp.zeros_like(phi_sorted)
-            return out.at[plan.tree.perm].set(phi_sorted), cfg
+            return unsort(fmm_evaluate(plan, cfg), plan.tree.perm), cfg
         cfg = dataclasses.replace(cfg, strong_cap=2 * cfg.strong_cap,
                                   weak_cap=0)
     from ..errors import CapOverflowError

@@ -198,6 +198,27 @@ def staged_lists(lists_seq, dummy: int, TB: int, SW: int):
     return combined, nchunk, steps
 
 
+def staged_grid_steps(lists_seq, dummy: int, TB: int, SW: int):
+    """The grid steps of each region that ``staged_lists`` stages from
+    the same arguments, and how many of them are empty: every one of
+    their TB x SW slots names the ``dummy`` row, so the step only stages
+    zeros (cap padding of the lists, or the box padding of the tiles).
+
+    Returns ``[(steps, empty), ...]``, one pair per region: ``steps`` a
+    Python int (B x tiles x the region's steps), ``empty`` an int32
+    scalar."""
+    combined, _, region_steps = staged_lists(lists_seq, dummy, TB, SW)
+    B, rows, cols = combined.shape
+    live = (combined != dummy).reshape(B, rows // TB, TB, cols // SW,
+                                       SW).any(axis=(2, 4))
+    counts, start = [], 0
+    for k in region_steps:
+        empty = jnp.sum(~live[..., start:start + k], dtype=jnp.int32)
+        counts.append((B * (rows // TB) * k, empty))
+        start += k
+    return counts
+
+
 def run_chunked(launch, nchunk: int, operands):
     """``launch(*chunk)`` over ``nchunk`` equal slices of the box axis
     (axis 1) of every operand, in sequence; the outputs' chunks are

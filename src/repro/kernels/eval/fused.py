@@ -207,6 +207,7 @@ def _eval_fused_pallas(p2p_lists, m2p_lists, tzr, tzi, trk, tr, ti, br, bi,
         )
         return pl.pallas_call(
             _make_kernel(p, P, kernel, TB, SW, p2p_steps, m2p_steps),
+            name="eval_fused",
             grid_spec=grid_spec,
             out_shape=[jax.ShapeDtypeStruct((B, crows, n_pad), dt)] * 2,
             compiler_params=pltpu.CompilerParams(

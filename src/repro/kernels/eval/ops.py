@@ -13,11 +13,12 @@ the ``p2l_sweep`` jnp scan.
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from ...core.config import FmmConfig
 from ..common import (dense_leaf_arrays, dense_rank_planes, round_up,
-                      scatter_from_leaves)
+                      scatter_from_leaves, staged_grid_steps)
 from .fused import eval_fused_pallas
 from .p2l import p2l_pallas
 
@@ -67,12 +68,28 @@ def eval_fused_apply(local, mult_leaf, tree, conn, cfg: FmmConfig,
         mrho = jnp.where(mask, rho[src], 0.0).astype(rdt)
         kwargs = {"ar": ar, "ai": ai, "mcr": mcr, "mci": mci, "mrho": mrho}
 
-    outr, outi = eval_fused_pallas(
-        conn.p2p, m2p_lists, zr[:-1], zi[:-1], rk[:-1], tr, ti, br, bi,
-        zr, zi, qr, qi, rk, p=cfg.p, kernel=cfg.kernel,
-        tile_boxes=cfg.tile_boxes, stage_width=cfg.stage_width,
-        interpret=interpret, **kwargs)
+    with jax.named_scope("eval_fused"):
+        outr, outi = eval_fused_pallas(
+            conn.p2p, m2p_lists, zr[:-1], zi[:-1], rk[:-1], tr, ti, br, bi,
+            zr, zi, qr, qi, rk, p=cfg.p, kernel=cfg.kernel,
+            tile_boxes=cfg.tile_boxes, stage_width=cfg.stage_width,
+            interpret=interpret, **kwargs)
     return scatter_from_leaves(outr + 1j * outi, idx, cfg.n)
+
+
+def eval_grid_steps(conn, cfg: FmmConfig):
+    """``(steps, empty)`` of each region of the grid ``eval_fused_apply``
+    launches: the P2P region, then the M2P region where the
+    configuration has one (``common.staged_grid_steps``)."""
+    regions = [conn.p2p] + ([conn.m2p] if cfg.use_p2l_m2p else [])
+    return staged_grid_steps([r[None] for r in regions], conn.p2p.shape[0],
+                             cfg.tile_boxes, cfg.stage_width)
+
+
+def p2l_grid_steps(conn, cfg: FmmConfig):
+    """``(steps, empty)`` of the grid ``p2l_apply`` launches."""
+    return staged_grid_steps([conn.p2l[None]], conn.p2l.shape[0],
+                             cfg.tile_boxes, cfg.stage_width)[0]
 
 
 def p2l_apply(tree, conn, cfg: FmmConfig, idx: np.ndarray, rho,
@@ -86,9 +103,10 @@ def p2l_apply(tree, conn, cfg: FmmConfig, idx: np.ndarray, rho,
     zr, zi, qr, qi, _ = dense_leaf_arrays(tree.z, tree.q, idx, n_pad)
     c = tree.centers[cfg.nlevels]
     P = round_up(cfg.p + 1, 128)
-    outr, outi = p2l_pallas(
-        conn.p2l, jnp.real(c).astype(rdt), jnp.imag(c).astype(rdt),
-        rho.astype(rdt), zr, zi, qr, qi, p=cfg.p, P=P, kernel=cfg.kernel,
-        tile_boxes=cfg.tile_boxes, stage_width=cfg.stage_width,
-        interpret=interpret)
+    with jax.named_scope("p2l"):
+        outr, outi = p2l_pallas(
+            conn.p2l, jnp.real(c).astype(rdt), jnp.imag(c).astype(rdt),
+            rho.astype(rdt), zr, zi, qr, qi, p=cfg.p, P=P,
+            kernel=cfg.kernel, tile_boxes=cfg.tile_boxes,
+            stage_width=cfg.stage_width, interpret=interpret)
     return (outr + 1j * outi)[:, : cfg.p + 1].astype(cfg.complex_dtype)

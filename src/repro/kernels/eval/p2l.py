@@ -157,6 +157,7 @@ def _p2l_pallas(lists, z0r, z0i, rho, xzr, xzi, xqr, xqi, *, p: int, P: int,
         )
         return pl.pallas_call(
             _make_kernel(p, P, kernel, TB, SW),
+            name="p2l",
             grid_spec=grid_spec,
             out_shape=[jax.ShapeDtypeStruct((B, crows, P), dt)] * 2,
             compiler_params=pltpu.CompilerParams(

@@ -46,6 +46,7 @@ def _l2p_pallas(br, bi, tr, ti, *, p: int, tile_boxes: int, interpret: bool):
     dt = tr.dtype
     outr, outi = pl.pallas_call(
         _make_kernel(p),
+        name="l2p",
         grid=(B, ntile),
         in_specs=[
             pl.BlockSpec((None, TB, P), row),

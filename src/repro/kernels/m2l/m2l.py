@@ -154,6 +154,7 @@ def _m2l_pallas(weak: jax.Array, ar, ai, prer, prei, postr, posti, logr,
         )
         return pl.pallas_call(
             _make_kernel(p, P, kernel, TB, SW),
+            name="m2l_fused",
             grid_spec=grid_spec,
             out_shape=[jax.ShapeDtypeStruct((B, crows, P), dt)] * 2,
             compiler_params=pltpu.CompilerParams(

@@ -12,11 +12,12 @@ Two entry points share one kernel:
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from ...core import expansions as E
 from ...core.config import FmmConfig
-from ..common import round_up
+from ..common import round_up, staged_grid_steps
 from .m2l import m2l_pallas
 
 
@@ -54,13 +55,14 @@ def _m2l_call(mult, weak, centers, cfg: FmmConfig, rho, interpret):
         kwargs = {"logr": jnp.real(logr).astype(rdt),
                   "logi": jnp.imag(logr).astype(rdt)}
 
-    outr, outi = m2l_pallas(
-        weak, ar, ai,
-        jnp.real(pre).astype(rdt), jnp.imag(pre).astype(rdt),
-        jnp.real(post).astype(rdt), jnp.imag(post).astype(rdt),
-        _hankel_t(cfg, P), p=cfg.p, kernel=cfg.kernel,
-        tile_boxes=cfg.tile_boxes, stage_width=cfg.stage_width,
-        interpret=interpret, **kwargs)
+    with jax.named_scope("m2l_fused"):
+        outr, outi = m2l_pallas(
+            weak, ar, ai,
+            jnp.real(pre).astype(rdt), jnp.imag(pre).astype(rdt),
+            jnp.real(post).astype(rdt), jnp.imag(post).astype(rdt),
+            _hankel_t(cfg, P), p=cfg.p, kernel=cfg.kernel,
+            tile_boxes=cfg.tile_boxes, stage_width=cfg.stage_width,
+            interpret=interpret, **kwargs)
     return (outr + 1j * outi)[:, : cfg.p + 1].astype(mult.dtype)
 
 
@@ -86,13 +88,28 @@ def m2l_fused_apply(mult, weak, centers, cfg: FmmConfig, rho,
     downward M2L. Returns the per-level (4**l, p+1) contributions.
     """
     levels = fused_levels(cfg)
-    offs = np.concatenate([[0], np.cumsum([4**l for l in levels])])
-    weak_flat = jnp.concatenate(
-        [jnp.where(weak[l] >= 0, weak[l] + int(offs[i]), -1)
-         for i, l in enumerate(levels)], axis=0)
+    weak_flat, offs = _flat_weak(weak, cfg)
     mult_flat = jnp.concatenate([mult[l] for l in levels], axis=0)
     centers_flat = jnp.concatenate([centers[l] for l in levels])
     rho_flat = jnp.concatenate([rho[l] for l in levels])
     out = _m2l_call(mult_flat, weak_flat, centers_flat, cfg, rho_flat,
                     interpret)
     return [out[int(offs[i]): int(offs[i + 1])] for i in range(len(levels))]
+
+
+def _flat_weak(weak, cfg: FmmConfig):
+    """The fused levels' weak lists on one flat box axis, each level's
+    entries shifted by its static box offset; returns (list, offsets)."""
+    levels = fused_levels(cfg)
+    offs = np.concatenate([[0], np.cumsum([4**l for l in levels])])
+    return jnp.concatenate(
+        [jnp.where(weak[l] >= 0, weak[l] + int(offs[i]), -1)
+         for i, l in enumerate(levels)], axis=0), offs
+
+
+def m2l_grid_steps(weak, cfg: FmmConfig):
+    """``(steps, empty)`` of the grid ``m2l_fused_apply`` launches on the
+    per-level weak lists ``weak`` (``common.staged_grid_steps``)."""
+    weak_flat, _ = _flat_weak(weak, cfg)
+    return staged_grid_steps([weak_flat[None]], weak_flat.shape[0],
+                             cfg.tile_boxes, cfg.stage_width)[0]

@@ -54,6 +54,7 @@ def _nbody_pallas(tzr, tzi, szr, szi, sqr, sqi, *, t_tile: int,
     r2 = lambda a, n: a.reshape(-1, n)
     outr, outi = pl.pallas_call(
         _nbody_kernel,
+        name="nbody",
         grid=(nt, ns),
         in_specs=[
             pl.BlockSpec((1, t_tile), tmap),

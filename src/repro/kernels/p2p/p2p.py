@@ -108,6 +108,7 @@ def _p2p_pallas(lists: jax.Array, tzr, tzi, trk, szr, szi, sqr, sqi, srk, *,
         )
         return pl.pallas_call(
             _make_kernel(kernel, TB, SW),
+            name="p2p",
             grid_spec=grid_spec,
             out_shape=[jax.ShapeDtypeStruct((B, crows, n_pad), dt)] * 2,
             compiler_params=pltpu.CompilerParams(

@@ -91,6 +91,7 @@ def _classify_pallas(cand, ccx, ccy, rc, tbx, tby, tbr, *, theta: float,
 
     outs = pl.pallas_call(
         _make_kernel(theta, use_p2l_m2p),
+        name="leaf_classify",
         grid=(ntile,),
         in_specs=[pl.BlockSpec((TB, Cp), tgt_map)] * 4
         + [pl.BlockSpec((TB, 1), tgt_map)] * 3,
@@ -117,9 +118,11 @@ def leaf_classify_pallas(cand, valid, centers, radii, cfg,
     rdt = cfg.real_dtype
     ccx, ccy, rc = _gather_geometry(cand, valid, centers, radii)
     cand = jnp.where(valid, cand, -1).astype(jnp.int32)
-    return _classify_pallas(cand, ccx.astype(rdt), ccy.astype(rdt),
-                            rc.astype(rdt), jnp.real(centers).astype(rdt),
-                            jnp.imag(centers).astype(rdt), radii.astype(rdt),
-                            theta=cfg.theta, use_p2l_m2p=cfg.use_p2l_m2p,
-                            tile_boxes=round_up(cfg.tile_boxes, 8),
-                            interpret=resolve_interpret(interpret))
+    with jax.named_scope("leaf_classify"):
+        return _classify_pallas(
+            cand, ccx.astype(rdt), ccy.astype(rdt), rc.astype(rdt),
+            jnp.real(centers).astype(rdt), jnp.imag(centers).astype(rdt),
+            radii.astype(rdt), theta=cfg.theta,
+            use_p2l_m2p=cfg.use_p2l_m2p,
+            tile_boxes=round_up(cfg.tile_boxes, 8),
+            interpret=resolve_interpret(interpret))

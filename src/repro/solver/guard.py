@@ -29,6 +29,12 @@ Cf. Holm et al. (arXiv:1311.1006) — re-planning online from measured
 feedback — and Agullo et al. (pipelined FMM over a runtime system) —
 runtime monitors keeping long pipelines healthy. DESIGN.md §9 documents
 the failure model and the cost of each rung.
+
+Host spans of the profiler (``jax.profiler.TraceAnnotation``):
+``fmm.guard.refresh`` around ``refresh_guarded``, ``fmm.guard.rung`` around
+each rung of a ladder walk (its name as the span's ``rung`` argument),
+``fmm.guard.read_margins`` and ``fmm.guard.health`` around the host reads
+of the margins and of the health plane.
 """
 from __future__ import annotations
 
@@ -37,6 +43,7 @@ from typing import Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.config import FmmConfig
 from ..core.direct import direct_potential
@@ -184,11 +191,13 @@ class GuardedSolver:
     def _attempt(self, solver: FmmSolver, z, q, rung: str, attempts: list,
                  batched: bool, note: str = ""):
         """Run one rung's health-instrumented apply; record the result."""
-        if batched:
-            phi, health = solver.apply_batched_with_health(z, q)
-        else:
-            phi, health = solver.apply_with_health(z, q)
-        h = host_health(health)
+        with TraceAnnotation("fmm.guard.rung", rung=rung):
+            if batched:
+                phi, health = solver.apply_batched_with_health(z, q)
+            else:
+                phi, health = solver.apply_with_health(z, q)
+            with TraceAnnotation("fmm.guard.health"):
+                h = host_health(health)
         ok = not (h["overflow"] or h["nonfinite_input"]
                   or h["nonfinite_output"])
         attempts.append(GuardAttempt(
@@ -213,8 +222,9 @@ class GuardedSolver:
         def one(zi, qi):
             return direct_potential(zi, zi, qi, kernel=kernel)
 
-        phi = (jax.vmap(one) if batched else one)(z, q)
-        finite = bool(np.all(np.isfinite(np.asarray(phi))))
+        with TraceAnnotation("fmm.guard.rung", rung="direct"):
+            phi = (jax.vmap(one) if batched else one)(z, q)
+            finite = bool(np.all(np.isfinite(np.asarray(phi))))
         attempts.append(GuardAttempt(
             rung="direct", backend="direct",
             strong_cap=self.solver.cfg.strong_cap,
@@ -301,32 +311,35 @@ class GuardedSolver:
         return its healthy plan. Returns ``(plan, GuardReport)``; feed
         the plan to ``apply_plan``. The steady-state cost over plain
         ``refresh`` is one host read of the margins vector."""
-        attempts: list[GuardAttempt] = []
-        solver = self.solver
-        for _ in range(self.max_cap_doublings + 1):
-            plan = solver.refresh(z, q)
-            margins, overflow = jax.device_get(
-                (plan.conn.margins, plan.conn.overflow))
-            m = {c: int(v) for c, v in zip(HEALTH_CLASSES, margins)}
-            ok = int(overflow) == 0
-            attempts.append(GuardAttempt(
-                rung="primary" if solver is self.solver
-                else f"caps*{solver.cfg.strong_cap}/{solver.cfg.weak_cap}",
-                backend=solver.dispatched["apply"],
-                strong_cap=solver.cfg.strong_cap,
-                weak_cap=solver.cfg.weak_cap, ok=ok,
-                overflow=int(overflow), margins=m))
-            if ok:
-                if solver is not self.solver:
-                    self.solver = solver       # promote the re-plan
-                return plan, self._report("refresh", attempts)
-            solver = FmmSolver.build(grow_caps(solver.cfg, m),
-                                     self.backend_name)
-        report = self._report("refresh", attempts)
-        raise CapOverflowError(
-            f"refresh: caps still overflow after {self.max_cap_doublings} "
-            f"doublings — {report.summary()}",
-            margins=attempts[-1].margins, overflow=attempts[-1].overflow)
+        with TraceAnnotation("fmm.guard.refresh"):
+            attempts: list[GuardAttempt] = []
+            solver = self.solver
+            for _ in range(self.max_cap_doublings + 1):
+                rung = ("primary" if solver is self.solver
+                        else f"caps*{solver.cfg.strong_cap}/{solver.cfg.weak_cap}")
+                with TraceAnnotation("fmm.guard.rung", rung=rung):
+                    plan = solver.refresh(z, q)
+                    with TraceAnnotation("fmm.guard.read_margins"):
+                        margins, overflow = jax.device_get(
+                            (plan.conn.margins, plan.conn.overflow))
+                m = {c: int(v) for c, v in zip(HEALTH_CLASSES, margins)}
+                ok = int(overflow) == 0
+                attempts.append(GuardAttempt(
+                    rung=rung, backend=solver.dispatched["apply"],
+                    strong_cap=solver.cfg.strong_cap,
+                    weak_cap=solver.cfg.weak_cap, ok=ok,
+                    overflow=int(overflow), margins=m))
+                if ok:
+                    if solver is not self.solver:
+                        self.solver = solver       # promote the re-plan
+                    return plan, self._report("refresh", attempts)
+                solver = FmmSolver.build(grow_caps(solver.cfg, m),
+                                         self.backend_name)
+            report = self._report("refresh", attempts)
+            raise CapOverflowError(
+                f"refresh: caps still overflow after {self.max_cap_doublings} "
+                f"doublings — {report.summary()}",
+                margins=attempts[-1].margins, overflow=attempts[-1].overflow)
 
     # -- lattice warm-up ----------------------------------------------------
 

@@ -31,6 +31,13 @@ across the batch.
 
 Backends (``repro.solver.backends``) swap the hot phases between the
 Pallas TPU kernels and the pure-jnp reference sweeps per phase.
+
+Each entry point runs under a host span of the profiler
+(``jax.profiler.TraceAnnotation``) named ``fmm.<entry>``, with
+``fmm.validate`` around its argument checks and ``fmm.dispatch`` around
+the jitted call; the compiled programs carry the phase scopes of
+``repro.core.fmm``. Without a running profiler the spans cost nothing
+measurable.
 """
 from __future__ import annotations
 
@@ -40,15 +47,17 @@ from collections import OrderedDict
 from typing import NamedTuple, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.config import FmmConfig
 from ..core.fmm import (HEALTH_CLASSES, FmmPlan, Health, fmm_build,
-                        fmm_evaluate, health_of)
+                        fmm_evaluate, health_of, unsort)
 from ..core.topology import connectivity_stats
 from ..errors import (BackendDowngradeWarning, CapOverflowError, DTypeError,
                       NonFiniteInputError, NonFiniteOutputError, ShapeError)
+from ..kernels.eval.ops import eval_grid_steps, p2l_grid_steps
+from ..kernels.m2l.ops import m2l_grid_steps
 from .autotune import TuneResult, tune_caps, tune_tiles
 from .backends import Backend, get_backend
 
@@ -171,6 +180,7 @@ class FmmSolver:
             self._make_core(batched_impls, batched_topo, with_health=True)))
         self._refresh = jax.jit(self._make_build(self._topo))
         self._apply_plan = jax.jit(self._make_evaluate(self._impls))
+        self._grid_steps = jax.jit(self._make_grid_steps())
         self.tune_result: Optional[TuneResult] = None
 
     # -- construction -------------------------------------------------------
@@ -213,7 +223,7 @@ class FmmSolver:
         """
         for fn in (self._apply, self._apply_batched, self._apply_health,
                    self._apply_batched_health, self._refresh,
-                   self._apply_plan):
+                   self._apply_plan, self._grid_steps):
             fn.clear_cache()
 
     def _compiled_program_count(self) -> int:
@@ -222,7 +232,7 @@ class FmmSolver:
         return sum(fn._cache_size() for fn in
                    (self._apply, self._apply_batched, self._apply_health,
                     self._apply_batched_health, self._refresh,
-                    self._apply_plan))
+                    self._apply_plan, self._grid_steps))
 
     @classmethod
     def cache_info(cls) -> CacheInfo:
@@ -248,20 +258,37 @@ class FmmSolver:
 
         def evaluate(plan: FmmPlan) -> jax.Array:
             self.trace_counts["evaluate"] += 1
-            phi_sorted = fmm_evaluate(plan, cfg, **impls)
-            out = jnp.zeros_like(phi_sorted)
-            return out.at[plan.tree.perm].set(phi_sorted)
+            return unsort(fmm_evaluate(plan, cfg, **impls), plan.tree.perm)
 
         return evaluate
+
+    def _make_grid_steps(self):
+        """``(steps, empty)`` of each staged Pallas grid that this
+        solver's ``apply`` launches on a plan's lists (see
+        ``kernels.common.staged_grid_steps``): the fused M2L, the fused
+        evaluation's P2P and M2P regions, and P2L, as far as the backend
+        runs those kernels."""
+        cfg, be = self.cfg, self.backend
+
+        def grid_steps(conn) -> dict:
+            out = {}
+            if be.m2l_fused is not None:
+                out["m2l"] = m2l_grid_steps(conn.weak, cfg)
+            if be.eval_fused is not None:
+                regions = eval_grid_steps(conn, cfg)
+                out.update(zip(("eval_p2p", "eval_m2p"), regions))
+            if be.p2l is not None and cfg.use_p2l_m2p and cfg.nlevels > 0:
+                out["p2l"] = p2l_grid_steps(conn, cfg)
+            return out
+
+        return grid_steps
 
     def _make_core(self, impls: dict, topo: dict, with_health: bool = False):
         cfg = self.cfg
 
         def core(z: jax.Array, q: jax.Array) -> jax.Array:
             plan = fmm_build(z, q, cfg, **topo)
-            phi_sorted = fmm_evaluate(plan, cfg, **impls)
-            out = jnp.zeros_like(phi_sorted)
-            phi = out.at[plan.tree.perm].set(phi_sorted)
+            phi = unsort(fmm_evaluate(plan, cfg, **impls), plan.tree.perm)
             if with_health:
                 return phi, health_of(plan, z, q, phi)
             return phi
@@ -279,8 +306,11 @@ class FmmSolver:
         sample, and use ``apply_checked``/``apply_guarded`` (or monitor
         ``stats``) when production inputs may drift from it.
         """
-        self._validate(z, q, "apply")
-        return self._apply(z, q)
+        with TraceAnnotation("fmm.apply"):
+            with TraceAnnotation("fmm.validate"):
+                self._validate(z, q, "apply")
+            with TraceAnnotation("fmm.dispatch"):
+                return self._apply(z, q)
 
     def apply_with_health(self, z: jax.Array, q: jax.Array):
         """``apply`` plus the in-graph health plane: ``(phi, Health)``
@@ -289,8 +319,11 @@ class FmmSolver:
         checking execution health costs one ``device_get``, not a second
         eager topology build. The guarded ladder (``repro.solver.guard``)
         builds on this entry point."""
-        self._validate(z, q, "apply_with_health")
-        return self._apply_health(z, q)
+        with TraceAnnotation("fmm.apply_with_health"):
+            with TraceAnnotation("fmm.validate"):
+                self._validate(z, q, "apply_with_health")
+            with TraceAnnotation("fmm.dispatch"):
+                return self._apply_health(z, q)
 
     def apply_checked(self, z: jax.Array, q: jax.Array) -> jax.Array:
         """``apply`` with execution-health validation on the same launch.
@@ -322,18 +355,24 @@ class FmmSolver:
         silently drops interactions. ``apply_batched_checked`` adds the
         batch-wide overflow guard.
         """
-        self._validate_batched(z, q)
-        self._warn_batched_fallback()
-        return self._apply_batched(z, q)
+        with TraceAnnotation("fmm.apply_batched"):
+            with TraceAnnotation("fmm.validate"):
+                self._validate_batched(z, q)
+            self._warn_batched_fallback()
+            with TraceAnnotation("fmm.dispatch"):
+                return self._apply_batched(z, q)
 
     def apply_batched_with_health(self, z: jax.Array, q: jax.Array):
         """``apply_batched`` plus the per-row health plane:
         ``(phi (B, N), Health)`` with every health field carrying a
         leading B axis — one compiled launch, reduce with
         ``host_health``."""
-        self._validate_batched(z, q)
-        self._warn_batched_fallback()
-        return self._apply_batched_health(z, q)
+        with TraceAnnotation("fmm.apply_batched_with_health"):
+            with TraceAnnotation("fmm.validate"):
+                self._validate_batched(z, q)
+            self._warn_batched_fallback()
+            with TraceAnnotation("fmm.dispatch"):
+                return self._apply_batched_health(z, q)
 
     def apply_batched_checked(self, z: jax.Array, q: jax.Array) -> jax.Array:
         """``apply_batched`` with execution-health validation across the
@@ -411,8 +450,11 @@ class FmmSolver:
         Feed the plan to ``apply_plan``; check ``plan.conn.overflow``
         (one scalar) to monitor cap drift as particles move.
         """
-        self._validate(z, q, "refresh")
-        return self._refresh(z, q)
+        with TraceAnnotation("fmm.refresh"):
+            with TraceAnnotation("fmm.validate"):
+                self._validate(z, q, "refresh")
+            with TraceAnnotation("fmm.dispatch"):
+                return self._refresh(z, q)
 
     def apply_plan(self, plan: FmmPlan) -> jax.Array:
         """Evaluate on a prebuilt plan (from ``refresh``); input order.
@@ -421,15 +463,26 @@ class FmmSolver:
         topology/evaluation seam, so a time-stepper can rebuild the plan
         every step, inspect it (overflow, stats) without extra builds,
         or evaluate one plan several times."""
-        return self._apply_plan(plan)
+        with TraceAnnotation("fmm.apply_plan"), \
+                TraceAnnotation("fmm.dispatch"):
+            return self._apply_plan(plan)
 
     def plan(self, z: jax.Array, q: jax.Array) -> FmmPlan:
         """Topological phase only (tree + connectivity) for inspection."""
         return self.refresh(z, q)   # shares refresh's shape validation
 
     def stats(self, z: jax.Array, q: jax.Array) -> dict:
-        """Connectivity stats (incl. ``overflow``) for one problem."""
-        return connectivity_stats(self.plan(z, q).conn)
+        """Connectivity stats (incl. ``overflow`` and the margins) for one
+        problem, and under ``"grid_steps"`` the grid of each staged
+        Pallas kernel ``apply`` launches on it: ``{kernel: {"steps": n,
+        "empty": k}}``, ``k`` of the ``n`` steps staging only the dummy
+        row (cap and tile padding). One ``device_get`` for all of it."""
+        conn = self.plan(z, q).conn
+        conn, grid = jax.device_get((conn, self._grid_steps(conn)))
+        stats = connectivity_stats(conn)
+        stats["grid_steps"] = {k: {"steps": int(n), "empty": int(e)}
+                               for k, (n, e) in grid.items()}
+        return stats
 
     def guarded(self, **kwargs) -> "GuardedSolver":  # noqa: F821
         """Wrap this solver's config/backend in the guarded-execution
