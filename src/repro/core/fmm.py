@@ -9,7 +9,7 @@ same name, with the sub-scopes in parentheses:
                the scatter back to input order (unsort)
 
 The scopes land in every device op's ``tf_op`` in a profiler trace
-(``jit(core)/upward/p2m/scatter-add``), so a trace times each phase (Table
+(``jit(core)/topology/sort/gather``), so a trace times each phase (Table
 5.1 / Figs 5.1, 5.3, 5.7). The per-phase functions are exposed
 individually so the Pallas kernels in ``repro.kernels`` can replace the
 hot ones (P2P, M2L) one at a time.
@@ -31,7 +31,7 @@ from . import expansions as E
 from .config import FmmConfig
 from .topology import (MARGIN_CLASSES, Connectivity, Tree,
                        build_connectivity, build_tree, leaf_ids,
-                       leaf_particle_index)
+                       leaf_particle_index, leaf_planes)
 
 
 class FmmPlan(NamedTuple):
@@ -106,29 +106,28 @@ def effective_radii(tree: Tree, cfg: FmmConfig) -> list[jax.Array]:
 # ---------------------------------------------------------------------------
 
 def p2m(tree: Tree, cfg: FmmConfig, rho=None) -> jax.Array:
-    """Leaf multipole expansions, radius-normalized; (4**L, p+1) complex."""
-    nb = cfg.nboxes
-    lid = jnp.asarray(leaf_ids(cfg))
+    """Leaf multipole expansions, radius-normalized; (4**L, p+1) complex.
+
+    A dense reduction over the static leaf planes (``leaf_planes``): each
+    power is summed along a leaf's row, so no per-particle scatter runs."""
     if rho is None:
         rho = effective_radii(tree, cfg)[cfg.nlevels]
-    w = (tree.z - tree.centers[cfg.nlevels][lid]) / rho[lid]
-
-    def seg(v):
-        return jax.ops.segment_sum(v, lid, num_segments=nb,
-                                   indices_are_sorted=True)
+    z, q = leaf_planes(tree, cfg)                         # (4**L, n_max)
+    rho = rho[:, None]
+    w = (z - tree.centers[cfg.nlevels][:, None]) / rho
 
     if cfg.kernel == "harmonic":
-        coeffs = [jnp.zeros(nb, tree.q.dtype)]
-        pw = tree.q / rho[lid]
+        coeffs = [jnp.zeros(cfg.nboxes, q.dtype)]
+        pw = q / rho
         for _ in range(cfg.p):
-            coeffs.append(-seg(pw))
+            coeffs.append(-pw.sum(axis=-1))
             pw = pw * w
     else:  # log: a~_0 = sum q; a~_j = -sum q w^j / j  (w already /rho)
-        coeffs = [seg(tree.q)]
-        pw = tree.q
+        coeffs = [q.sum(axis=-1)]
+        pw = q
         for j in range(1, cfg.p + 1):
             pw = pw * w
-            coeffs.append(-seg(pw) / j)
+            coeffs.append(-pw.sum(axis=-1) / j)
     return jnp.stack(coeffs, axis=-1)
 
 

@@ -16,13 +16,13 @@ of the input data". This package is that phase for the TPU port:
 ``from repro.core import build_tree, build_connectivity``.
 """
 from .tree import (Tree, build_tree, build_tree_lexsort, leaf_ids,
-                   leaf_particle_index, leaf_particle_index_loop)
+                   leaf_particle_index, leaf_particle_index_loop, leaf_planes)
 from .connectivity import (MARGIN_CLASSES, Connectivity, build_connectivity,
                            connectivity_stats, leaf_classify_reference)
 
 __all__ = [
     "Tree", "build_tree", "build_tree_lexsort", "leaf_ids",
-    "leaf_particle_index", "leaf_particle_index_loop",
+    "leaf_particle_index", "leaf_particle_index_loop", "leaf_planes",
     "Connectivity", "MARGIN_CLASSES", "build_connectivity",
     "connectivity_stats", "leaf_classify_reference",
 ]

@@ -39,7 +39,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..config import FmmConfig, level_bounds, segment_ids, split_bounds
+from ..config import (FmmConfig, leaf_sizes, level_bounds, segment_ids,
+                      split_bounds)
 
 
 class Tree(NamedTuple):
@@ -233,6 +234,26 @@ def leaf_particle_index(cfg: FmmConfig) -> np.ndarray:
     col = np.arange(n_max, dtype=np.int64)
     idx = lb[:-1, None] + col[None, :]
     return np.where(col[None, :] < sizes[:, None], idx, -1).astype(np.int32)
+
+
+def leaf_planes(tree: Tree, cfg: FmmConfig) -> tuple[jax.Array, jax.Array]:
+    """Rank-sorted (z, q) laid out as (4**L, n_max) leaf planes.
+
+    Leaf b's row holds its rank slice ``[lb[b], lb[b+1])``. When every
+    leaf has the same size the slices tile the ranks, and the planes are
+    a reshape (no gather, no scatter); otherwise one gather through
+    ``leaf_particle_index``, whose padded slots get q = 0 and the leaf's
+    center as position, so powers of (z - center) stay finite.
+    """
+    sizes = leaf_sizes(cfg)
+    if sizes.min() == sizes.max():
+        return tree.z.reshape(len(sizes), -1), tree.q.reshape(len(sizes), -1)
+    idx = leaf_particle_index(cfg)
+    valid = jnp.asarray(idx >= 0)
+    safe = jnp.asarray(np.maximum(idx, 0))
+    z = jnp.where(valid, tree.z[safe], tree.centers[cfg.nlevels][:, None])
+    q = jnp.where(valid, tree.q[safe], 0)
+    return z, q
 
 
 def leaf_particle_index_loop(cfg: FmmConfig) -> np.ndarray:
