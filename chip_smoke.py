@@ -10,7 +10,11 @@ process, on one chip:
      (normal) particles;
   2. ``apply_batched`` on B = 8 problems of N = 3584 (near the
      FMM/direct break-even);
-  3. one wave of ragged requests through a ``ServePlane``.
+  3. one wave of ragged requests through a ``ServePlane``;
+  4. the logarithmic kernel on 2**18 clustered charges: the Pallas
+     backend against the reference backend on complex phi, which sees
+     the kernels' ``atan2`` and the log's branches, and Re phi against
+     the direct sum.
 
 Every phase checks its answer against an independent O(N**2) sum in f64
 numpy on the host, and that it ran the Pallas kernels (no silent
@@ -40,6 +44,12 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 #: The f32 accuracy bound of the FMM at p = 17 against the direct sum
 #: (tests/test_fmm_accuracy.py, ``test_f32_reaches_single_precision_floor``).
 F32_TOL = 5e-4
+#: Pallas against the reference backend on complex phi, logarithmic
+#: kernel, as a share of max |phi| (tests/test_log_kernel.py): both run
+#: the same expansions with the same float32 ``log`` and take its branch
+#: at the same points, so they differ by rounding; a branch taken the
+#: other way moves Im phi by 2 pi |q| of one charge.
+LOG_AGREE = 1e-5
 
 
 def _say(record: dict) -> None:
@@ -127,6 +137,58 @@ def phase_main(dist: str, n: int, *, seed: int = 0, samples: int = 512,
         "peak_bytes_in_use": _peak_bytes()}
     _say(record)
     _check_err(err, record["phase"])
+    return record
+
+
+def phase_log(n: int, *, seed: int = 200, samples: int = 256,
+              backend: str = "auto") -> dict:
+    """The logarithmic kernel on normal (sigma 0.1) positions and real
+    charges: caps tuned to the problem, then ``apply`` on ``backend`` and
+    on the reference backend at the same configuration. Complex phi must
+    agree to ``LOG_AGREE`` of its largest value; Re phi (Im phi is
+    defined up to multiples of 2 pi q) is checked against the direct sum
+    at ``samples`` targets."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from repro.configs.fmm2d import fmm_config
+    from repro.core.direct import direct_potential_numpy
+    from repro.solver import FmmSolver
+
+    cfg = dataclasses.replace(fmm_config(n), kernel="log")
+    zh, qh = host_problem("normal", n, seed, cfg.complex_dtype)
+    qh = qh.real.astype(qh.dtype)
+    z, q = jnp.asarray(zh), jnp.asarray(qh)
+    solver = FmmSolver.build(cfg, backend).tune(z, q, tiles=False)
+    if solver.dispatched["apply"] != "pallas":
+        raise AssertionError(f"apply dispatched {solver.dispatched}")
+    phi, t_steady = _timed(lambda: solver.apply(z, q))
+    phi = np.asarray(phi, np.complex128)
+    ref = np.asarray(FmmSolver.build(solver.cfg, "reference").apply(z, q),
+                     np.complex128)
+    diff = np.abs(phi - ref)
+    agree = {"normwise": float(diff.max() / np.abs(ref).max()),
+             "real": float(np.abs(phi.real - ref.real).max()),
+             "imag": float(np.abs(phi.imag - ref.imag).max())}
+    targets = np.random.default_rng(seed + 1).choice(
+        n, min(samples, n), replace=False)
+    direct = direct_potential_numpy(zh[targets], zh, qh, kernel="log").real
+    err_re = float(np.abs(phi.real[targets] - direct).max()
+                   / np.abs(direct).max())
+    record = {
+        "phase": "log/normal", "n": n, "p": cfg.p, "dtype": cfg.dtype,
+        "caps": [solver.cfg.strong_cap, solver.cfg.weak_cap],
+        "host_s_apply": t_steady, "pallas_vs_reference": agree,
+        "re_err_vs_direct": err_re, "direct_targets": len(targets),
+        "peak_bytes_in_use": _peak_bytes()}
+    _say(record)
+    if not agree["normwise"] < LOG_AGREE:
+        raise AssertionError(f"log: pallas and reference differ by "
+                             f"{agree['normwise']:.3e} of max |phi|")
+    if not err_re <= F32_TOL:
+        raise AssertionError(f"log: Re phi error {err_re:.3e} against the "
+                             f"direct sum exceeds {F32_TOL:g}")
     return record
 
 
@@ -249,6 +311,7 @@ def main() -> int:
     t0 = time.perf_counter()
     phases = [(f"main/{dist}", phase_main, (dist, 1 << 20))
               for dist in ("uniform", "normal")]
+    phases += [("log", phase_log, (1 << 18,))]
     phases += [("batched", phase_batched, (3584, 8)),
                ("serving", phase_serving, (512, 2048, 16))]
     for name, phase, args in phases:

@@ -12,6 +12,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .mathf import clog
+
 
 @functools.partial(jax.jit, static_argnames=("kernel", "chunk"))
 def direct_potential(z_eval: jax.Array, z_src: jax.Array, q: jax.Array,
@@ -28,7 +30,7 @@ def direct_potential(z_eval: jax.Array, z_src: jax.Array, q: jax.Array,
         if kernel == "harmonic":
             c = jnp.where(ok, q[None, :] / safe, 0.0)
         else:
-            c = jnp.where(ok, q[None, :] * jnp.log(-safe), 0.0)
+            c = jnp.where(ok, q[None, :] * clog(-safe), 0.0)
         return carry, c.sum(axis=-1)
 
     _, phi = jax.lax.scan(body, 0, ze.reshape(-1, chunk))

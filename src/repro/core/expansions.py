@@ -30,6 +30,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .mathf import clog
+
 #: Precision of every matrix product here: XLA's default f32 matmul on a
 #: TPU rounds operands to bf16, which costs the f32 FMM two orders of
 #: magnitude of accuracy at p = 17 (the binomial tables reach ~1e9).
@@ -125,7 +127,7 @@ def m2l_apply(a: jax.Array, r: jax.Array, mat: jax.Array) -> jax.Array:
     b_hat = jnp.einsum("...k,lk->...l", a_hat, mat, precision=HIGHEST)
     b = b_hat * inv_pows(-r, p)
     # log-source correction on the constant term
-    return b.at[..., 0].add(a[..., 0] * jnp.log(r))
+    return b.at[..., 0].add(a[..., 0] * clog(r))
 
 
 def l2l_apply(b: jax.Array, s: jax.Array, mat: jax.Array) -> jax.Array:
@@ -209,7 +211,7 @@ def m2l_horner(a: jax.Array, r: jax.Array) -> jax.Array:
             b[j] = b[j] + b[j - 1]
     a0 = a[..., 0]
     w = jnp.ones_like(r)
-    out = [b[0] + a0 * jnp.log(r)]
+    out = [b[0] + a0 * clog(r)]
     for j in range(1, p + 1):
         w = w * (-rinv)
         out.append((b[j] - a0 / j) * w)
@@ -258,7 +260,7 @@ def p2l_single(x: jax.Array, q: jax.Array, z0: jax.Array, p: int,
         return jnp.stack(coeffs, axis=-1)
     elif kernel == "log":
         # q log(z - x) = q log(z0 - x) - q sum_l ((z-z0) w)^l / l
-        coeffs = [jnp.sum(q * jnp.log(z0 - x))]
+        coeffs = [jnp.sum(q * clog(z0 - x))]
         pw = q * w
         for l in range(1, p + 1):
             coeffs.append(-jnp.sum(pw) / l)
@@ -275,7 +277,7 @@ def eval_multipole(a: jax.Array, z0: jax.Array, z: jax.Array) -> jax.Array:
     for j in range(p - 1, 0, -1):
         acc = acc * w + a[..., j]
     acc = acc * w
-    return acc + a[..., 0] * jnp.log(z - z0)
+    return acc + a[..., 0] * clog(z - z0)
 
 
 def eval_local(b: jax.Array, z0: jax.Array, z: jax.Array) -> jax.Array:
@@ -375,7 +377,7 @@ def m2l_norm(a: jax.Array, r: jax.Array, rho_s: jax.Array,
     a_hat = a * pre
     b_hat = jnp.einsum("...k,lk->...l", a_hat, mat, precision=HIGHEST)
     b = b_hat * pows(-rho_t / r, p)
-    return b.at[..., 0].add(a[..., 0] * jnp.log(r))
+    return b.at[..., 0].add(a[..., 0] * clog(r))
 
 
 def m2l_norm_horner(a: jax.Array, r: jax.Array, rho_s: jax.Array,
@@ -397,7 +399,7 @@ def m2l_norm_horner(a: jax.Array, r: jax.Array, rho_s: jax.Array,
     a0 = a[..., 0]
     wt = -rho_t / r
     w = jnp.ones_like(r)
-    out = [b[0] + a0 * jnp.log(r)]
+    out = [b[0] + a0 * clog(r)]
     for j in range(1, p + 1):
         w = w * wt
         out.append((b[j] - a0 / j) * w)

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from . import expansions as E
 from .config import FmmConfig
+from .mathf import clog
 from .topology import (MARGIN_CLASSES, Connectivity, Tree,
                        build_connectivity, build_tree, leaf_ids,
                        leaf_particle_index, leaf_planes)
@@ -229,7 +230,7 @@ def p2l_sweep(local: jax.Array, tree: Tree, conn: Connectivity,
                 updates.append(pw.sum(axis=-1))
                 pw = pw * w
         else:
-            logs = jnp.where(pmask, jnp.log(z0[:, None] - pz), 0.0)
+            logs = jnp.where(pmask, clog(z0[:, None] - pz), 0.0)
             updates = [(pq * logs).sum(axis=-1)]
             pw = pq * w
             for l in range(1, cfg.p + 1):
@@ -317,7 +318,7 @@ def m2p_sweep(phi: jax.Array, mult_leaf: jax.Array, tree: Tree,
         acc = acc * w
         if cfg.kernel == "log":
             acc = acc + a[:, 0] * jnp.where(
-                mask, jnp.log(jnp.where(mask, dz, 1.0)), 0.0)
+                mask, clog(jnp.where(mask, dz, 1.0)), 0.0)
         return acc_phi + jnp.where(mask, acc, 0.0), None
 
     out, _ = jax.lax.scan(body, phi, conn.m2p.T)
@@ -354,7 +355,7 @@ def p2p_sweep(phi: jax.Array, tree: Tree, conn: Connectivity,
                        / jnp.where(ok, diff, 1.0))
         else:
             contrib = jnp.where(ok, sq[:, None, :]
-                                * jnp.log(jnp.where(ok, -diff, 1.0)), 0.0)
+                                * clog(jnp.where(ok, -diff, 1.0)), 0.0)
         return acc + contrib.sum(axis=-1), None
 
     acc, _ = jax.lax.scan(body, jnp.zeros_like(tz), conn.p2p.T)

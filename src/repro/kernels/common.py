@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..core.mathf import atan2, log, negate
+
 
 #: Block index 0 for BlockSpec index maps. A bare Python ``0`` traces
 #: as int64 under ``jax_enable_x64``, and Mosaic refuses 64-bit index
@@ -270,6 +272,14 @@ def dense_leaf_arrays(z: jax.Array, q: jax.Array, idx: np.ndarray,
     return pack(zr), pack(zi), pack(qr), pack(qi), jnp.pad(valid, ((0, 1), (0, pad_cols)))
 
 
+def launch_name(base: str, kernel: str) -> str:
+    """A launch's name under G-kernel ``kernel``: ``base`` for the harmonic
+    kernel, ``<base>_<kernel>`` otherwise, so a trace tells the variants
+    apart. The launch's ``jax.named_scope`` and its ``pallas_call(name=)``
+    both take it."""
+    return base if kernel == "harmonic" else f"{base}_{kernel}"
+
+
 def pairwise_tile(kernel: str, tzr, tzi, trk, szr, szi, qr, qi, srk):
     """One staged P2P source tile against the resident targets.
 
@@ -292,8 +302,8 @@ def pairwise_tile(kernel: str, tzr, tzi, trk, szr, szi, qr, qi, srk):
         return (((qr * dx + qi * dy) * inv).sum(axis=-1),
                 ((qi * dx - qr * dy) * inv).sum(axis=-1))
     # q * log(z_t - z_s) = q * (log|d| + i*arg(-dx, -dy))
-    lr = jnp.where(ok, 0.5 * jnp.log(d2), 0.0)
-    li = jnp.where(ok, jnp.arctan2(-dy, -dx), 0.0)
+    lr = jnp.where(ok, 0.5 * log(d2), 0.0)
+    li = jnp.where(ok, atan2(negate(dy), negate(dx)), 0.0)
     return ((qr * lr - qi * li).sum(axis=-1),
             (qr * li + qi * lr).sum(axis=-1))
 

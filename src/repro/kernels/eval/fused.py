@@ -46,7 +46,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common import (ZERO, broadcast_unbatched, l2p_horner,
+from ...core.mathf import atan2, log
+from ..common import (ZERO, broadcast_unbatched, l2p_horner, launch_name,
                       pad_boxes, pairwise_tile, prefetch_row_specs,
                       resolve_interpret, row_view, run_chunked, slot_spec,
                       slot_view, staged_lists)
@@ -125,8 +126,8 @@ def _make_kernel(p: int, P: int, kernel: str, TB: int, SW: int,
                     fi = accr * wi + acci * wr
                     if kernel == "log":
                         # + a_0 * log(z - z0_src)
-                        lr = jnp.where(ok, 0.5 * jnp.log(d2), 0.0)
-                        li = jnp.where(ok, jnp.arctan2(dxi, dxr), 0.0)
+                        lr = jnp.where(ok, 0.5 * log(d2), 0.0)
+                        li = jnp.where(ok, atan2(dxi, dxr), 0.0)
                         a0r, a0i = ar[:, 0:1], ai[:, 0:1]
                         fr = fr + a0r * lr - a0i * li
                         fi = fi + a0r * li + a0i * lr
@@ -207,7 +208,7 @@ def _eval_fused_pallas(p2p_lists, m2p_lists, tzr, tzi, trk, tr, ti, br, bi,
         )
         return pl.pallas_call(
             _make_kernel(p, P, kernel, TB, SW, p2p_steps, m2p_steps),
-            name="eval_fused",
+            name=launch_name("eval_fused", kernel),
             grid_spec=grid_spec,
             out_shape=[jax.ShapeDtypeStruct((B, crows, n_pad), dt)] * 2,
             compiler_params=pltpu.CompilerParams(

@@ -17,8 +17,8 @@ import jax
 import jax.numpy as jnp
 
 from ...core.config import FmmConfig
-from ..common import (dense_leaf_arrays, dense_rank_planes, round_up,
-                      scatter_from_leaves, staged_grid_steps)
+from ..common import (dense_leaf_arrays, dense_rank_planes, launch_name,
+                      round_up, scatter_from_leaves, staged_grid_steps)
 from .fused import eval_fused_pallas
 from .p2l import p2l_pallas
 
@@ -68,7 +68,7 @@ def eval_fused_apply(local, mult_leaf, tree, conn, cfg: FmmConfig,
         mrho = jnp.where(mask, rho[src], 0.0).astype(rdt)
         kwargs = {"ar": ar, "ai": ai, "mcr": mcr, "mci": mci, "mrho": mrho}
 
-    with jax.named_scope("eval_fused"):
+    with jax.named_scope(launch_name("eval_fused", cfg.kernel)):
         outr, outi = eval_fused_pallas(
             conn.p2p, m2p_lists, zr[:-1], zi[:-1], rk[:-1], tr, ti, br, bi,
             zr, zi, qr, qi, rk, p=cfg.p, kernel=cfg.kernel,
@@ -103,7 +103,7 @@ def p2l_apply(tree, conn, cfg: FmmConfig, idx: np.ndarray, rho,
     zr, zi, qr, qi, _ = dense_leaf_arrays(tree.z, tree.q, idx, n_pad)
     c = tree.centers[cfg.nlevels]
     P = round_up(cfg.p + 1, 128)
-    with jax.named_scope("p2l"):
+    with jax.named_scope(launch_name("p2l", cfg.kernel)):
         outr, outi = p2l_pallas(
             conn.p2l, jnp.real(c).astype(rdt), jnp.imag(c).astype(rdt),
             rho.astype(rdt), zr, zi, qr, qi, p=cfg.p, P=P,

@@ -33,8 +33,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common import (ZERO, make_batched_op, pad_boxes, prefetch_row_specs,
-                      resolve_interpret, row_view, run_chunked, staged_lists)
+from ...core.mathf import atan2, log
+from ..common import (ZERO, launch_name, make_batched_op, pad_boxes,
+                      prefetch_row_specs, resolve_interpret, row_view,
+                      run_chunked, staged_lists)
 
 
 def _make_kernel(p: int, P: int, kernel: str, TB: int, SW: int):
@@ -93,10 +95,12 @@ def _make_kernel(p: int, P: int, kernel: str, TB: int, SW: int):
                     ni = pwr * wi + pwi * wr
                     pwr, pwi = nr, ni
             else:
-                # b~_0 = sum q log(z0 - x) = sum q log(-d)
-                lr = jnp.where(ok, 0.5 * jnp.log(jnp.where(ok, d2, 1.0)),
+                # b~_0 = sum q log(z0 - x); z0 - x formed as such, not
+                # as -(x - z0), so a tie takes the zero's sign (and the
+                # log's branch) the reference's complex z0 - x has
+                lr = jnp.where(ok, 0.5 * log(jnp.where(ok, d2, 1.0)),
                                0.0)
-                li = jnp.where(ok, jnp.arctan2(-dxi, -dxr), 0.0)
+                li = jnp.where(ok, atan2(z0i - xi, z0r - xr), 0.0)
                 cols_r = [red(qr * lr - qi * li)]
                 cols_i = [red(qr * li + qi * lr)]
                 pwr = qr * wr - qi * wi
@@ -157,7 +161,7 @@ def _p2l_pallas(lists, z0r, z0i, rho, xzr, xzi, xqr, xqi, *, p: int, P: int,
         )
         return pl.pallas_call(
             _make_kernel(p, P, kernel, TB, SW),
-            name="p2l",
+            name=launch_name("p2l", kernel),
             grid_spec=grid_spec,
             out_shape=[jax.ShapeDtypeStruct((B, crows, P), dt)] * 2,
             compiler_params=pltpu.CompilerParams(

@@ -9,6 +9,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from ...core.mathf import clog
+
 
 def m2p_ref(lists, tzr, tzi, ar, ai, mcr, mci, mrho, p: int,
             kernel: str = "harmonic"):
@@ -34,6 +36,6 @@ def m2p_ref(lists, tzr, tzi, ar, ai, mcr, mci, mrho, p: int,
     acc = acc * w
     if kernel == "log":
         acc = acc + a[..., 0:1] * jnp.where(
-            ok, jnp.log(jnp.where(ok, dz, 1.0)), 0.0)
+            ok, clog(jnp.where(ok, dz, 1.0)), 0.0)
     phi = jnp.where(ok, acc, 0.0).sum(axis=1)
     return jnp.real(phi), jnp.imag(phi)

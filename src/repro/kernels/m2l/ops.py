@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from ...core import expansions as E
 from ...core.config import FmmConfig
+from ...core.mathf import clog
 from ..common import round_up, staged_grid_steps
 from .m2l import m2l_pallas
 
@@ -51,9 +52,10 @@ def _m2l_call(mult, weak, centers, cfg: FmmConfig, rho, interpret):
 
     kwargs = {}
     if cfg.kernel == "log":
-        logr = jnp.log(r)                                # masked slots: log 1
-        kwargs = {"logr": jnp.real(logr).astype(rdt),
-                  "logi": jnp.imag(logr).astype(rdt)}
+        with jax.named_scope("log_planes"):
+            logr = clog(r)                               # masked slots: log 1
+            kwargs = {"logr": jnp.real(logr).astype(rdt),
+                      "logi": jnp.imag(logr).astype(rdt)}
 
     with jax.named_scope("m2l_fused"):
         outr, outi = m2l_pallas(

@@ -3,6 +3,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from ...core.mathf import clog
+
 
 def p2p_ref(lists, tzr, tzi, trk, szr, szi, sqr, sqi, srk,
             kernel: str = "harmonic"):
@@ -26,6 +28,6 @@ def p2p_ref(lists, tzr, tzi, trk, szr, szi, sqr, sqi, srk,
         c = jnp.where(ok, sq[:, None, :, :], 0.0) / jnp.where(ok, diff, 1.0)
     else:
         c = jnp.where(ok, sq[:, None, :, :]
-                      * jnp.log(jnp.where(ok, -diff, 1.0)), 0.0)
+                      * clog(jnp.where(ok, -diff, 1.0)), 0.0)
     phi = c.sum(axis=(2, 3))
     return jnp.real(phi), jnp.imag(phi)

@@ -20,6 +20,12 @@ def test_main_phase_tunes_applies_and_matches_direct(dist):
     assert rec["tile_trials_host_s"]       # the sweep reported its trials
 
 
+def test_log_phase_agrees_with_reference_backend_and_direct():
+    rec = chip_smoke.phase_log(2048, samples=64, backend="pallas")
+    assert rec["pallas_vs_reference"]["normwise"] < chip_smoke.LOG_AGREE
+    assert rec["re_err_vs_direct"] <= chip_smoke.F32_TOL
+
+
 def test_batched_phase_checks_every_row():
     rec = chip_smoke.phase_batched(300, 2, backend="pallas")
     assert rec["dispatched"]["apply_batched"] == "pallas"

@@ -63,36 +63,43 @@ def _compile(fn, shapes, one_chip):
     return compiled
 
 
-def _eval_fused(b, tb, sw):
+def _eval_fused(b, tb, sw, s=S, kernel="harmonic"):
     f32, i32 = jnp.float32, jnp.int32
     tgt, src = (b, NBOX, N_PAD), (b, NBOX + 1, N_PAD)
-    shapes = ([((b, NBOX, S), i32)] * 2                    # p2p, m2p lists
+    shapes = ([((b, NBOX, s), i32)] * 2                    # p2p, m2p lists
               + [(tgt, f32)] * 2 + [(tgt, i32)] + [(tgt, f32)] * 2
               + [((b, NBOX, P), f32)] * 2                  # local coeffs
               + [(src, f32)] * 4 + [(src, i32)]            # source planes
               + [((b, NBOX + 1, P), f32)] * 2              # multipoles
-              + [((b, NBOX, S), f32)] * 3)                 # m2p slot planes
-    fn = functools.partial(eval_fused_pallas_batched, p=CFG.p,
+              + [((b, NBOX, s), f32)] * 3)                 # m2p slot planes
+    fn = functools.partial(eval_fused_pallas_batched, p=CFG.p, kernel=kernel,
                            tile_boxes=tb, stage_width=sw, interpret=False)
     return fn, shapes
 
 
-def _m2l_fused(b, tb, sw):
+def _m2l_fused(b, tb, sw, w=W, kernel="harmonic"):
     f32 = jnp.float32
-    shapes = ([((b, NBOX_M2L, W), jnp.int32)]
+    shapes = ([((b, NBOX_M2L, w), jnp.int32)]
               + [((b, NBOX_M2L + 1, P), f32)] * 2
-              + [((b, NBOX_M2L, W), f32)] * 4 + [((P, P), f32)])
-    fn = functools.partial(m2l_pallas_batched, p=CFG.p, tile_boxes=tb,
-                           stage_width=sw, interpret=False)
-    return fn, shapes
+              + [((b, NBOX_M2L, w), f32)] * 4 + [((P, P), f32)])
+    fn = functools.partial(m2l_pallas_batched, p=CFG.p, kernel=kernel,
+                           tile_boxes=tb, stage_width=sw, interpret=False)
+    if kernel == "harmonic":
+        return fn, shapes
+
+    def with_log_planes(*args):
+        *rest, logr, logi = args
+        return fn(*rest, logr=logr, logi=logi)
+
+    return with_log_planes, shapes + [((b, NBOX_M2L, w), f32)] * 2
 
 
-def _p2l(b, tb, sw):
+def _p2l(b, tb, sw, s=S, kernel="harmonic"):
     f32 = jnp.float32
-    shapes = ([((b, NBOX, S), jnp.int32)] + [((b, NBOX), f32)] * 3
+    shapes = ([((b, NBOX, s), jnp.int32)] + [((b, NBOX), f32)] * 3
               + [((b, NBOX + 1, N_PAD), f32)] * 4)
-    fn = functools.partial(p2l_pallas_batched, p=CFG.p, P=P, tile_boxes=tb,
-                           stage_width=sw, interpret=False)
+    fn = functools.partial(p2l_pallas_batched, p=CFG.p, P=P, kernel=kernel,
+                           tile_boxes=tb, stage_width=sw, interpret=False)
     return fn, shapes
 
 
@@ -111,6 +118,17 @@ def _leaf_classify(b, tb, sw):
 
 KERNELS = {"eval_fused": _eval_fused, "m2l_fused": _m2l_fused,
            "p2l": _p2l, "leaf_classify": _leaf_classify}
+
+#: The logarithmic kernel at the benchmark's clustered configuration
+#: (``bench/configs/fmm2d-1m-log.json``): its caps and its tiling.
+LOG_STRONG_CAP, LOG_WEAK_CAP = 512, 2024
+LOG_TILING = (8, 4)
+LOG_KERNELS = {
+    "eval_fused": functools.partial(_eval_fused, s=LOG_STRONG_CAP,
+                                    kernel="log"),
+    "m2l_fused": functools.partial(_m2l_fused, w=LOG_WEAK_CAP, kernel="log"),
+    "p2l": functools.partial(_p2l, s=LOG_STRONG_CAP, kernel="log"),
+}
 
 
 @pytest.mark.parametrize("tiling", TILINGS, ids=lambda t: "tb%d-sw%d" % t)
@@ -131,3 +149,17 @@ def test_described_chip_is_a_v5e(one_chip):
     dev = next(iter(one_chip.device_set))
     assert dev.platform == "tpu"
     assert "v5" in dev.device_kind.lower(), dev.device_kind
+
+
+@pytest.mark.parametrize("batch", [1, 4])
+@pytest.mark.parametrize("kernel", sorted(LOG_KERNELS))
+def test_log_kernel_compiles_for_v5e_at_paper_scale(kernel, batch, one_chip):
+    """The logarithmic variants, whose ``atan2`` Mosaic lowers only as the
+    kernels' own polynomial (``core.mathf.atan2``), at the clustered
+    configuration's caps and tiling."""
+    fn, shapes = LOG_KERNELS[kernel](batch, *LOG_TILING)
+    compiled = _compile(fn, shapes, one_chip)
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < 16e9 * 0.9, total

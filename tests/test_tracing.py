@@ -95,6 +95,37 @@ def test_tpu_lowering_names_each_kernel_before_pallas_call(entry,
     assert calls == {f"{k}/pallas_call" for k in KERNELS}
 
 
+#: The logarithmic kernel's launches, named apart from the harmonic ones.
+LOG_KERNELS = ["eval_fused_log", "m2l_fused", "p2l_log", "leaf_classify"]
+LOG_PATHS = ["downward/m2l/log_planes/", "downward/p2l/p2l_log/",
+             "evaluation/eval_fused_log/"]
+
+
+def test_log_kernel_names_its_launches_and_log_planes(monkeypatch):
+    """Under a log configuration, lowered for the chip, the log variants
+    of the fused evaluation and the P2L are ``eval_fused_log`` and
+    ``p2l_log`` (scope and ``pallas_call`` alike), the XLA complex log of
+    the M2L planes sits under ``log_planes``, and the harmonic
+    configuration's names are unchanged."""
+    import dataclasses
+    monkeypatch.setattr(common, "default_interpret", lambda: False)
+    names = {}
+    with jax.enable_x64(False):
+        for kernel in ("harmonic", "log"):
+            cfg = dataclasses.replace(CFG, kernel=kernel)
+            program, fn, args, _ = _entries(FmmSolver(cfg, "pallas"))[0]
+            text = fn.trace(*args).lower(
+                lowering_platforms=("tpu",)).as_text(debug_info=True)
+            names[kernel] = set(re.findall(r'loc\("([^"]*)"', text))
+    for kernel, launches in (("harmonic", KERNELS), ("log", LOG_KERNELS)):
+        calls = {n for n in names[kernel] if n.endswith("/pallas_call")}
+        assert calls == {f"{k}/pallas_call" for k in launches}, kernel
+    for path in LOG_PATHS:
+        assert any(n.startswith("jit(core)/" + path) for n in names["log"])
+    assert not any("log_planes" in n or "_log/" in n
+                   for n in names["harmonic"] if n.startswith("jit("))
+
+
 # ---------------------------------------------------------------------------
 # grid-step counter
 # ---------------------------------------------------------------------------
