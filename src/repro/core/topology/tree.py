@@ -19,11 +19,22 @@ x resp. y. Each median split is then O(N) sort-free work:
     min/max of a sorted run are its endpoints), giving the eccentric-axis
     choice without a segmented reduction;
   * "goes left" is a static positional predicate in the chosen axis's
-    array (the first ceil(n/2) entries of the segment), scattered to
-    particle ids;
-  * both arrays are *stable-partitioned* at the static median ranks with
-    one cumulative sum — the classic presorted kd-tree construction,
-    mapped to scatters so every step is an O(N) data-parallel primitive.
+    array (the first ceil(n/2) entries of the segment): ONE scatter puts
+    it on the particle ids, through the chosen array of each segment;
+  * the chosen array stays in place — its left half already sits at
+    ``[start, mid)`` in order, so its stable partition is the identity;
+  * the other array reads the flag with ONE gather and is
+    stable-partitioned at the static median ranks with a segmented
+    cumulative sum and ONE scatter — the classic presorted kd-tree
+    construction, mapped to scatters so every step is an O(N)
+    data-parallel primitive.
+
+The per-rank bookkeeping (segment start, median rank, offset, the split
+axis of each rank's segment) is arithmetic on the rank and a reshape
+when 4**L divides N, so every segment of every split has N / 2**s ranks;
+otherwise it is read from the (2**s,)-entry bound tables through each
+rank's segment id. Either way a split moves N ids through two scatters
+and one gather.
 
 The final rank order equals the lexsort cascade's for inputs with
 distinct coordinates (ties break by initial argsort order instead of the
@@ -59,26 +70,10 @@ def _seg_minmax(v: jax.Array, sid: jax.Array, nseg: int):
     return mn, mx
 
 
-def _partition(order, left_of, starts_pos, mids_pos, offs_pos):
-    """Stable-partition ``order`` within static segments by a per-id flag.
-
-    ``order``: (N,) int32 particle ids, segment-contiguous at the static
-    bounds and internally sorted by one coordinate. ``left_of``: (N,)
-    int32 0/1 flag per particle *id* (not bool: the TPU compiler builds
-    an int32 scatter of 2**20 entries in under a second, a bool one in
-    tens of seconds). ``starts_pos``/``mids_pos``/``offs_pos``: (N,)
-    per-position segment start / median rank / offset within the
-    segment. Left entries keep their relative order in ``[start, mid)``,
-    right entries in ``[mid, end)`` — so both coordinate orders survive
-    every split without re-sorting.
-    """
-    f = left_of[order]
-    # exclusive count of lefts before p; int32 even under x64
-    lefts = jnp.cumsum(f, dtype=jnp.int32) - f
-    seg_l = lefts - lefts[starts_pos]              # lefts before p in segment
-    seg_r = offs_pos - seg_l                       # rights before p in segment
-    dest = jnp.where(f > 0, starts_pos + seg_l, mids_pos + seg_r)
-    return jnp.zeros_like(order).at[dest].set(order)
+def _per_position(v: jax.Array, size: int) -> jax.Array:
+    """Broadcast a per-segment array to the ranks of equal segments of
+    ``size``: a repeat by reshape, not a gather."""
+    return jnp.broadcast_to(v[:, None], (v.shape[0], size)).reshape(-1)
 
 
 def build_tree(z: jax.Array, q: jax.Array, cfg: FmmConfig) -> Tree:
@@ -88,7 +83,8 @@ def build_tree(z: jax.Array, q: jax.Array, cfg: FmmConfig) -> Tree:
     rows of one (2, N) stable sort, which the TPU compiler also builds in
     about a third of the time two 1-D sorts of 2**20 take; everything
     else is cumsum/gather/scatter. The jaxpr sort-count test in
-    tests/test_topology.py pins this property.
+    tests/test_topology.py pins this property, and a second jaxpr test
+    pins the N-long gathers and scatters of the split loop.
     """
     rdt = cfg.real_dtype
     cdt = cfg.complex_dtype
@@ -107,39 +103,73 @@ def build_tree(z: jax.Array, q: jax.Array, cfg: FmmConfig) -> Tree:
             dimension=1, num_keys=1, is_stable=True)
         ax, ay = order[0], order[1]                # argsort of x, of y
         sb = split_bounds(N, 2 * L)
-        split_x = None
-        # Per-position segment bookkeeping is derived in-graph from the
-        # (2**s,)-entry bound tables: (N,)-sized numpy constants per
-        # split would embed hundreds of MB into the program at N ~ 1e6.
+        # Every split of every level has equal segments of N / 2**s
+        # ranks iff 4**L divides N: the per-rank bookkeeping is then
+        # arithmetic on the rank. Otherwise it is read from the
+        # (2**s,)-entry bound tables through each rank's segment id
+        # (N-sized numpy constants per split would embed hundreds of MB
+        # into the program at N ~ 1e6).
+        equal = N % 4**L == 0
         pos = jnp.arange(N, dtype=jnp.int32)
-        sid_pos = jnp.zeros(N, jnp.int32)          # segment of each rank
+        # segment of each rank, for the table path only
+        sid_pos = None if equal else jnp.zeros(N, jnp.int32)
         for s in range(2 * L):
             b = sb[s]
-            mids = sb[s + 1][1::2]
-            starts_pos = jnp.asarray(b[:-1], jnp.int32)[sid_pos]
-            mids_pos = jnp.asarray(mids, jnp.int32)[sid_pos]
-            offs_pos = pos - starts_pos
             # sorted-run endpoints ARE the segment extents: 2 gathers/axis
             jst = jnp.asarray(b[:-1], jnp.int32)
             jla = jnp.asarray(b[1:] - 1, jnp.int32)
             xmn, xmx = x[ax[jst]], x[ax[jla]]
             ymn, ymx = y[ay[jst]], y[ay[jla]]
             split_x = (xmx - xmn) >= (ymx - ymn)           # (2**s,)
-            # positional "first half of my segment" flag, static per rank
+            if equal:
+                size = N >> s
+                sx_pos = _per_position(split_x, size)
+                offs_pos = pos % size
+                starts_pos = pos - offs_pos
+                mids_pos = starts_pos + (size + 1) // 2
+            else:
+                sx_pos = split_x[sid_pos]
+                starts_pos = jnp.asarray(b[:-1], jnp.int32)[sid_pos]
+                mids_pos = jnp.asarray(sb[s + 1][1::2], jnp.int32)[sid_pos]
+                offs_pos = pos - starts_pos
+            # "goes left" = first ceil(n/2) ranks of the segment in the
+            # chosen axis's array. There the left half already sits in
+            # [start, mid) in order, so that array stays as it is; only
+            # the other one is stable-partitioned.
             pos_left = (pos < mids_pos).astype(jnp.int32)
-            xleft = jnp.zeros(N, jnp.int32).at[ax].set(pos_left)
-            yleft = jnp.zeros(N, jnp.int32).at[ay].set(pos_left)
-            sid_of_id = jnp.zeros(N, jnp.int32).at[ax].set(sid_pos)
-            goes_left = jnp.where(split_x[sid_of_id], xleft, yleft)
-            ax = _partition(ax, goes_left, starts_pos, mids_pos, offs_pos)
-            ay = _partition(ay, goes_left, starts_pos, mids_pos, offs_pos)
-            # children of segment k are 2k (left half) and 2k+1
-            sid_pos = 2 * sid_pos + 1 - pos_left
+            chosen = jnp.where(sx_pos, ax, ay)
+            other = jnp.where(sx_pos, ay, ax)
+            # per-id flag (int32, not bool: the TPU compiler builds an
+            # int32 scatter of 2**20 entries in under a second, a bool
+            # one in tens of seconds); within each segment ``chosen`` is
+            # a permutation of the segment's ids, so every id is written
+            goes_left = jnp.zeros(N, jnp.int32).at[chosen].set(pos_left)
+            f = goes_left[other]
+            # exclusive count of lefts before each rank in its segment
+            # (int32 even under x64)
+            if equal:
+                fs = f.reshape(-1, size)
+                seg_l = (jnp.cumsum(fs, axis=1, dtype=jnp.int32)
+                         - fs).reshape(N)
+            else:
+                lefts = jnp.cumsum(f, dtype=jnp.int32) - f
+                seg_l = lefts - lefts[starts_pos]
+            seg_r = offs_pos - seg_l                       # rights before
+            dest = jnp.where(f > 0, starts_pos + seg_l, mids_pos + seg_r)
+            moved = jnp.zeros_like(other).at[dest].set(other)
+            ax = jnp.where(sx_pos, ax, moved)
+            ay = jnp.where(sx_pos, moved, ay)
+            if not equal:
+                # children of segment k are 2k (left half) and 2k+1
+                sid_pos = 2 * sid_pos + 1 - pos_left
         # Final rank order within each leaf = ascending in the axis its
         # parent split on (what the lexsort cascade leaves behind): both
         # id arrays are leaf-contiguous at the same static bounds, so the
         # choice is a positionwise select.
-        choose_x = split_x[sid_pos // 2]
+        if equal:
+            choose_x = _per_position(split_x, N >> (2 * L - 1))
+        else:
+            choose_x = split_x[sid_pos // 2]
         perm = jnp.where(choose_x, ax, ay)
 
     xs, ys = x[perm], y[perm]
@@ -153,16 +183,23 @@ def build_tree(z: jax.Array, q: jax.Array, cfg: FmmConfig) -> Tree:
 def _level_geometry(xs, ys, cfg: FmmConfig):
     """Shrink-to-fit centers/radii for every level from ONE segmented pass.
 
-    The four segmented min/max reductions run once, over the leaf boxes;
-    every coarser level's extents are 4-child min/max reductions of the
-    (4**l,) level arrays (exact: min over a box == min of its children's
-    mins), so the O(N) geometry work is not repeated per level.
+    The four segmented min/max reductions run once, over the leaf boxes
+    (row-wise over a reshape when the leaves are equal, else scattered
+    through the leaf ids); every coarser level's extents are 4-child
+    min/max reductions of the (4**l,) level arrays (exact: min over a box
+    == min of its children's mins), so the O(N) geometry work is not
+    repeated per level.
     """
     rdt, cdt = cfg.real_dtype, cfg.complex_dtype
-    lid = jnp.asarray(leaf_ids(cfg))
     nb = 4 ** cfg.nlevels
-    xmn, xmx = _seg_minmax(xs, lid, nb)
-    ymn, ymx = _seg_minmax(ys, lid, nb)
+    if cfg.n % nb == 0:                 # equal leaves: row-wise reductions
+        xl, yl = xs.reshape(nb, -1), ys.reshape(nb, -1)
+        xmn, xmx = xl.min(axis=1), xl.max(axis=1)
+        ymn, ymx = yl.min(axis=1), yl.max(axis=1)
+    else:
+        lid = jnp.asarray(leaf_ids(cfg))
+        xmn, xmx = _seg_minmax(xs, lid, nb)
+        ymn, ymx = _seg_minmax(ys, lid, nb)
     centers: list = [None] * (cfg.nlevels + 1)
     radii: list = [None] * (cfg.nlevels + 1)
     for l in range(cfg.nlevels, -1, -1):

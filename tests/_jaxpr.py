@@ -31,3 +31,19 @@ def count_pallas_calls(jaxpr) -> int:
 def count_sorts(jaxpr) -> int:
     """Recursively count sort eqns in a jaxpr (incl. sub-jaxprs)."""
     return count_eqns(jaxpr, "sort")
+
+
+def count_indexed(jaxpr, name: str, n: int) -> int:
+    """Recursively count eqns of one indexing primitive (``gather``,
+    ``scatter``, ...) whose index operand, the eqn's second input, has
+    ``n`` rows: the N-long gathers and scatters of a build over N points,
+    not the small-table lookups."""
+    k = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            shape = getattr(eqn.invars[1].aval, "shape", ())
+            k += bool(shape) and shape[0] == n
+        for v in eqn.params.values():
+            for sub in _sub_jaxprs(v):
+                k += count_indexed(sub, name, n)
+    return k

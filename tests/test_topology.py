@@ -8,9 +8,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 from _hypothesis_fallback import given, settings, st
-from _jaxpr import count_sorts
+from _jaxpr import count_indexed, count_sorts
 
 from repro.core import FmmConfig, build_connectivity
+from repro.core.config import level_bounds, split_bounds
 from repro.core.topology import (build_tree, build_tree_lexsort,
                                  connectivity_stats, leaf_particle_index,
                                  leaf_particle_index_loop)
@@ -32,7 +33,8 @@ def _tree_pair(n, levels, dist="uniform", seed=0, **kw):
 @pytest.mark.parametrize("n,levels,dist",
                          [(64, 1, "uniform"), (257, 2, "normal"),
                           (1024, 3, "layer"), (50, 0, "normal"),
-                          (4096, 3, "normal")])
+                          (4096, 3, "normal"), (1000, 2, "uniform"),
+                          (4097, 3, "layer")])
 def test_tree_parity_with_lexsort_oracle(n, levels, dist):
     """Rank layout bit-identical to the seed lexsort cascade."""
     cfg, new, old = _tree_pair(n, levels, dist, seed=n)
@@ -68,6 +70,79 @@ def test_build_tree_at_most_two_sorts():
         jo = jax.make_jaxpr(functools.partial(build_tree_lexsort, cfg=cfg))(
             jnp.asarray(z), jnp.asarray(q))
         assert count_sorts(jo.jaxpr) == 2 * levels  # what we replaced
+
+
+@pytest.mark.parametrize("n,levels", [(1 << 20, 7), (1 << 16, 5),
+                                      (3584, 3), (1000, 2)])
+def test_build_tree_n_long_gathers_and_scatters(n, levels):
+    """Per split one flag scatter, one gather and one partition scatter
+    of N: ≤ 4L N-long scatters, and under equal segments (4**L divides
+    N) ≤ 2L + 4 N-long gathers, the final rank permutation's included
+    (the table path of unequal segments reads per-rank bookkeeping
+    through gathers). Traced, not run."""
+    cfg = FmmConfig(n=n, nlevels=levels, p=17)
+    z = jax.ShapeDtypeStruct((n,), jnp.complex64)
+    jx = jax.make_jaxpr(functools.partial(build_tree, cfg=cfg))(z, z).jaxpr
+    assert count_indexed(jx, "scatter", n) <= 4 * levels
+    if n % 4**levels == 0:
+        assert count_indexed(jx, "gather", n) <= 2 * levels + 4
+
+
+def _assert_kd_invariants(tree, z, cfg):
+    """``perm`` permutes the input; at every split the left child's
+    largest coordinate on the split axis is ≤ the right child's
+    smallest; centers and radii are the boxes' direct min/max."""
+    perm = np.asarray(tree.perm)
+    assert (np.sort(perm) == np.arange(cfg.n)).all()
+    zs = np.asarray(z)[perm]
+    assert (np.asarray(tree.z) == zs).all()
+    x, y = zs.real, zs.imag
+    sb = split_bounds(cfg.n, 2 * cfg.nlevels)
+    for s in range(2 * cfg.nlevels):
+        b = sb[s]
+        for a, e, m in zip(b[:-1], b[1:], sb[s + 1][1::2]):
+            ex = x[a:e].max() - x[a:e].min()
+            ey = y[a:e].max() - y[a:e].min()
+            c = x if ex >= ey else y
+            assert c[a:m].max() <= c[m:e].min(), (s, a)
+    for l, bounds in enumerate(level_bounds(cfg)):
+        for k, (a, e) in enumerate(zip(bounds[:-1], bounds[1:])):
+            xmn, xmx = x[a:e].min(), x[a:e].max()
+            ymn, ymx = y[a:e].min(), y[a:e].max()
+            c = np.asarray(tree.centers[l])[k]
+            assert c == complex(0.5 * (xmn + xmx), 0.5 * (ymn + ymx))
+            r = 0.5 * jnp.hypot(xmx - xmn, ymx - ymn)   # the build's hypot
+            assert np.asarray(tree.radii[l])[k] == r
+
+
+@pytest.mark.parametrize("n,levels,dist",
+                         [(4096, 3, "uniform"), (4096, 3, "normal"),
+                          (3584, 3, "layer"), (1000, 2, "normal")])
+def test_tree_kd_invariants_with_ties(n, levels, dist):
+    """Coordinates rounded to a grid of 1/8: many equal coordinates, so
+    the medians fall inside runs of ties."""
+    z, q = particles(dist, n, n + 1)
+    z = np.round(z.real * 8) / 8 + 1j * (np.round(z.imag * 8) / 8)
+    cfg = FmmConfig(n=n, nlevels=levels, p=5, dtype="f64")
+    tree = build_tree(jnp.asarray(z), jnp.asarray(q), cfg)
+    _assert_kd_invariants(tree, z, cfg)
+
+
+def test_build_tree_vmap_matches_single_calls():
+    """The batched path (``apply_batched`` vmaps the build): 4 problems
+    of 3584 equal the stacked single builds, bit for bit."""
+    cfg = FmmConfig(n=3584, nlevels=3, p=17)
+    zq = [particles(d, 3584, 20 + i)
+          for i, d in enumerate(["uniform", "normal"] * 2)]
+    z = jnp.asarray(np.stack([a for a, _ in zq]), jnp.complex64)
+    q = jnp.asarray(np.stack([b for _, b in zq]), jnp.complex64)
+    build = functools.partial(build_tree, cfg=cfg)
+    batched = jax.jit(jax.vmap(build))(z, q)
+    single = [jax.jit(build)(z[i], q[i]) for i in range(4)]
+    for got, *want in zip(jax.tree_util.tree_leaves(batched),
+                          *map(jax.tree_util.tree_leaves, single)):
+        assert (np.asarray(got) == np.stack([np.asarray(w)
+                                             for w in want])).all()
 
 
 def test_connectivity_compaction_is_batched():
